@@ -55,8 +55,9 @@ JAX engines are timed on a steady-state call (after one compile call,
 whose cost is reported separately as ``compile_s`` and whose number of
 XLA program compiles — counted via ``jax.monitoring`` — lands in
 ``compile_count``); jobs/sec for the batched engines counts all
-replications.  With ``--cache-dir`` the
-persistent compilation cache is enabled and each jitted cell additionally
+replications.  The persistent compilation cache is on (at
+``JAX_COMPILATION_CACHE_DIR``, else ``--cache-dir``, else
+``<checkout>/.jax_cache``), and each jitted cell additionally
 reports ``compile_warm_s`` — the retrace-plus-cache-load cost measured by
 clearing the in-memory jit caches and re-dispatching — so a compile-cache
 regression (warm ≈ cold) is visible in the committed rows; on a second
@@ -154,12 +155,13 @@ def _warm_compile_s(fn, wall: float) -> float | None:
     """Retrace + compile-cache-load cost of ``fn``'s executable.
 
     Only measured when the persistent compilation cache is enabled
-    (``--cache-dir``): the in-memory jit caches are dropped so the next
+    (``enable_compile_cache``): the in-memory jit caches are dropped so the next
     dispatch re-traces and reloads the executable from the cache dir —
     the steady-state ``wall`` is subtracted out.  Returns None (skipped)
     without a cache: clearing would only re-measure the cold compile.
     """
-    if not jax.config.jax_compilation_cache_dir:
+    if not (jax.config.jax_enable_compilation_cache
+            and jax.config.jax_compilation_cache_dir):
         return None
     jax.clear_caches()
     t0 = time.time()
@@ -387,7 +389,7 @@ SRPT_SMOKE = {"ks": (64,), "python_k": 64, "jobs": 1_200, "reps": 2,
 #: sorts are the scan's whole cost, so an oversized Q is pure slowdown
 #: (overflow would raise, not mis-simulate; see ``_srpt_args``).  The
 #: ``pallas`` sub-config runs the fused-kernel rows at their own reduced
-#: scale: off-TPU the kernels execute in interpret mode, one replication
+#: scale: the kernels run only in interpret mode (CPU), one replication
 #: at a time, so the full 32-rep cells would take hours while measuring
 #: only the interpreter — the committed pallas cells track the engine's
 #: trajectory at a fixed small topology instead
@@ -611,8 +613,8 @@ def main(argv=None):
                "'jax-shard' shards the replications axis across the local "
                "device mesh (pair with --devices N — any CPU box can "
                "expose N host devices); 'pallas' is the fused "
-               "step-kernel family of repro.kernels.msj_scan — off-TPU it "
-               "executes in Pallas interpret mode (one replication at a "
+               "step-kernel family of repro.kernels.msj_scan — it runs "
+               "only on the CPU, in Pallas interpret mode (one replication at a "
                "time, unfused XLA ops), so its CPU rows track correctness "
                "and trajectory, not the fused speed. "
                "fig1_critical/fig2_regimes accept the same "
@@ -645,17 +647,21 @@ def main(argv=None):
                          "rows (default: honor an existing XLA_FLAGS "
                          "entry, else 1); must run before JAX init")
     ap.add_argument("--cache-dir", default=None,
-                    help="persistent JAX compilation-cache dir; enables "
-                         "the compile_warm_s column")
+                    help="persistent JAX compilation-cache dir (default: "
+                         "<checkout>/.jax_cache; JAX_COMPILATION_CACHE_DIR "
+                         "wins when set)")
     ap.add_argument("--ks", type=int, nargs="+", default=None)
     ap.add_argument("--jobs", type=int, default=None)
     ap.add_argument("--reps", type=int, default=None)
     ap.add_argument("--python-jobs", type=int, default=None)
     ap.add_argument("--out", default="BENCH_sim.json")
     args = ap.parse_args(argv)
+    from repro.core.shard import enable_compile_cache
+
     from .common import configure_scan_runtime
-    configure_scan_runtime(devices=args.devices, cache_dir=args.cache_dir,
-                           warn=True)   # loud if something beat us to init
+    # warn=True: loud if something beat us to init
+    configure_scan_runtime(devices=args.devices, warn=True)
+    enable_compile_cache(args.cache_dir)
     if args.smoke:
         ks, jobs, reps, pj, tk = (64,), 20_000, 4, 2_000, 256
         stream_cfg = STREAM_SMOKE

@@ -25,27 +25,25 @@ ENGINE_HELP = ("jax = batched vmap scans (default); jax-shard = the same "
                "scans with replications sharded across the local device "
                "mesh (combine with --devices N on any CPU box), "
                "bit-identical to jax; pallas = fused step kernels, "
-               "bit-identical but interpret-mode (slower) off-TPU; "
+               "bit-identical but interpret-mode (slower), CPU only; "
                "python = exact event engine, full paper policy set")
 
 
-def configure_scan_runtime(devices: int | None = None,
-                           cache_dir: str | None = None, *,
+def configure_scan_runtime(devices: int | None = None, *,
                            warn: bool = False) -> bool:
     """Configure the XLA runtime for the scan cores.
 
     Thin wrapper over :func:`repro.core.shard.configure_runtime`:
-    ``devices`` host devices, a 1-thread intra-op pool per device (the
-    per-op-dispatch win of the old single-thread pin, times N), and an
-    optional persistent compilation cache.  Benchmark *mains* call this
+    ``devices`` host devices and a 1-thread intra-op pool per device (the
+    per-op-dispatch win of the old single-thread pin, times N) on a CPU
+    backend; nothing on an accelerator.  Benchmark *mains* call this
     first with ``warn=True`` so a caller that raced backend init hears
     about the dead pool loudly; the opportunistic internal calls (every
     jax-engine helper routes through here) keep ``warn=False`` and simply
     inherit whatever runtime exists.
     """
     from repro.core.shard import configure_runtime
-    return configure_runtime(devices=devices, intra_op_threads=1,
-                             cache_dir=cache_dir, warn=warn)
+    return configure_runtime(devices=devices, intra_op_threads=1, warn=warn)
 
 
 def run_policies_jax(wl_factory, points, point_col: str, *, num_jobs: int,
@@ -61,7 +59,7 @@ def run_policies_jax(wl_factory, points, point_col: str, *, num_jobs: int,
     (parallel to ``points``) of extra per-point column dicts.  ``engine``
     is ``"jax"`` (vmapped scans), ``"jax-shard"`` (replications sharded
     over the local device mesh) or ``"pallas"`` (fused step kernels —
-    interpret mode off-TPU: bit-identical results, slower on CPU).
+    interpret mode, CPU only: bit-identical results, slower).
     ``grid=True`` (default) runs the sweep grid-natively — one
     ``engines.simulate_grid`` launch per policy over every
     not-yet-checkpointed point — and ``grid=False`` forces per-cell

@@ -16,12 +16,13 @@ Four engines:
   to ``jax``.
 * ``--engine pallas`` — same sweeps on the fused step kernels
   (``repro.kernels.msj_scan``); bit-identical to ``jax``, interpret mode
-  (slower) off-TPU.
+  (slower), CPU only.
 * ``--engine python`` — the exact event-driven engine over the full paper
   policy set (slow; use for the policies the scan substrate cannot cover).
 
-``--cache-dir`` points JAX's persistent compilation cache at a directory
-so repeated sweeps stop paying the per-(k, R, J) compile.
+JAX's persistent compilation cache is on (``JAX_COMPILATION_CACHE_DIR``,
+else ``--cache-dir``, else ``<checkout>/.jax_cache``), so repeated sweeps
+stop paying the per-(k, R, J) compile.
 """
 
 from __future__ import annotations
@@ -93,7 +94,9 @@ def main(argv=None):
     ap.add_argument("--devices", type=int, default=None,
                     help="host-platform device count (jax-shard sweeps)")
     ap.add_argument("--cache-dir", default=None,
-                    help="persistent JAX compilation-cache dir")
+                    help="persistent JAX compilation-cache dir (default: "
+                         "<checkout>/.jax_cache; JAX_COMPILATION_CACHE_DIR "
+                         "wins when set)")
     ap.add_argument("--ckpt-dir", default=None,
                     help="write each (k, policy) cell atomically here "
                          "(crash-resumable; batched engines only)")
@@ -102,9 +105,11 @@ def main(argv=None):
     args = ap.parse_args(argv)
     if args.engine == "python" and (args.ckpt_dir or args.resume):
         ap.error("--ckpt-dir/--resume need a batched engine (jax/...)")
+    from repro.core.shard import enable_compile_cache
+
     from .common import configure_scan_runtime
-    configure_scan_runtime(devices=args.devices, cache_dir=args.cache_dir,
-                           warn=True)
+    configure_scan_runtime(devices=args.devices, warn=True)
+    enable_compile_cache(args.cache_dir)
     default = 30_000 if args.engine == "python" else 100_000
     jobs = args.jobs if args.jobs is not None \
         else (1_000_000 if args.full else default)

@@ -5,13 +5,13 @@ Same job classes and server needs as Figure 1 (k = 512, f_k = 6).
 ``--engine jax`` (default) runs both sweeps on the batched vmap substrate
 (FCFS + ModifiedBS-FCFS + BS-FCFS proper with Def.-1 pull-backs, ``--reps``
 replications, mean/CI columns); the heavy-traffic sweep holds k fixed, so
-every load point reuses one compiled (k, R, J) executable — and with
-``--cache-dir`` the executable survives the process, so a re-run pays no
-compile at all.
+every load point reuses one compiled (k, R, J) executable — and the
+persistent compilation cache keeps it past the process, so a re-run pays
+no compile at all.
 ``--engine jax-shard`` shards the replications axis across the local
 device mesh (pair with ``--devices N``); bit-identical to ``jax``.
 ``--engine pallas`` runs the same sweeps on the fused step kernels
-(bit-identical; interpret mode — slower — off-TPU).
+(bit-identical; interpret mode — slower — CPU only).
 ``--engine python`` runs the event-driven engine over the full paper
 policy set.
 """
@@ -98,7 +98,9 @@ def main(argv=None):
     ap.add_argument("--devices", type=int, default=None,
                     help="host-platform device count (jax-shard sweeps)")
     ap.add_argument("--cache-dir", default=None,
-                    help="persistent JAX compilation-cache dir")
+                    help="persistent JAX compilation-cache dir (default: "
+                         "<checkout>/.jax_cache; JAX_COMPILATION_CACHE_DIR "
+                         "wins when set)")
     ap.add_argument("--ckpt-dir", default=None,
                     help="write each sweep cell atomically under "
                          "<dir>/{heavy,subcritical} (crash-resumable; "
@@ -108,9 +110,11 @@ def main(argv=None):
     args = ap.parse_args(argv)
     if args.engine == "python" and (args.ckpt_dir or args.resume):
         ap.error("--ckpt-dir/--resume need a batched engine (jax/...)")
+    from repro.core.shard import enable_compile_cache
+
     from .common import configure_scan_runtime
-    configure_scan_runtime(devices=args.devices, cache_dir=args.cache_dir,
-                           warn=True)
+    configure_scan_runtime(devices=args.devices, warn=True)
+    enable_compile_cache(args.cache_dir)
     default = 20_000 if args.engine == "python" else 100_000
     jobs = args.jobs if args.jobs is not None \
         else (1_000_000 if args.full else default)

@@ -17,11 +17,11 @@ exact Python engine — every fallback is announced by a once-per-process
 across the local device mesh (pair with ``--devices N``); ``--engine
 pallas`` routes all five scan policies — the preemptive srpt pair
 included, via the fused bitonic rank/permute kernels — through the fused
-step kernels (interpret mode off-TPU: bit-identical, not fast); ``--engine
+step kernels (interpret mode, CPU only: bit-identical, not fast); ``--engine
 python`` runs everything on the event engine over the *same* bootstrap
 batch, so rows are bit-comparable across engines (the ``engine`` column
-records the core that actually ran each row).  ``--cache-dir`` enables
-the persistent compilation cache.
+records the core that actually ran each row).  The persistent compilation
+cache is on (``--cache-dir`` moves it; ``JAX_COMPILATION_CACHE_DIR`` wins).
 """
 
 from __future__ import annotations
@@ -187,7 +187,9 @@ def main(argv=None):
     ap.add_argument("--devices", type=int, default=None,
                     help="host-platform device count (jax-shard rows)")
     ap.add_argument("--cache-dir", default=None,
-                    help="persistent JAX compilation-cache dir")
+                    help="persistent JAX compilation-cache dir (default: "
+                         "<checkout>/.jax_cache; JAX_COMPILATION_CACHE_DIR "
+                         "wins when set)")
     ap.add_argument("--ckpt-dir", default=None,
                     help="write each (dataset, k, load) cell atomically "
                          "here (crash-resumable)")
@@ -196,9 +198,11 @@ def main(argv=None):
     args = ap.parse_args(argv)
     if args.swf and (args.ckpt_dir or args.resume):
         ap.error("--ckpt-dir/--resume apply to the synthesized-table sweep")
+    from repro.core.shard import enable_compile_cache
+
     from .common import configure_scan_runtime
-    configure_scan_runtime(devices=args.devices, cache_dir=args.cache_dir,
-                           warn=True)
+    configure_scan_runtime(devices=args.devices, warn=True)
+    enable_compile_cache(args.cache_dir)
     jobs = 1_000_000 if args.full else args.jobs
     pols = tuple(args.policies or PAPER_POLICIES)
     if args.swf:

@@ -35,9 +35,9 @@ import sys
 import tempfile
 
 
-def _run(out: str, args: list[str], cache_dir: str) -> dict:
+def _run(out: str, args: list[str], cache_dir: str | None) -> dict:
     cmd = [sys.executable, "-m", "benchmarks.bench_sim", "--out", out,
-           "--cache-dir", cache_dir, *args]
+           *(["--cache-dir", cache_dir] if cache_dir else []), *args]
     print("+", " ".join(cmd[2:]), file=sys.stderr, flush=True)
     subprocess.run(cmd, check=True)
     with open(out) as f:
@@ -46,8 +46,12 @@ def _run(out: str, args: list[str], cache_dir: str) -> dict:
 
 def regenerate(topologies=(1, 2, 4), out="BENCH_sim.json",
                cache_dir=None) -> dict:
-    """Run bench_sim once per (topology, scale) and merge the rows."""
-    cache_dir = cache_dir or tempfile.mkdtemp(prefix="bench-jax-cache-")
+    """Run bench_sim once per (topology, scale) and merge the rows.
+
+    Every run shares one persistent compilation cache: bench_sim's own
+    (``JAX_COMPILATION_CACHE_DIR``, else ``cache_dir``, else the fixed
+    in-checkout path).
+    """
     parts = []
     with tempfile.TemporaryDirectory() as tmp:
         for n in topologies:
@@ -90,8 +94,8 @@ def main(argv=None):
     ap.add_argument("--out", default="BENCH_sim.json")
     ap.add_argument("--cache-dir", default=None,
                     help="persistent compile cache shared by all runs "
-                         "(default: a fresh temp dir, so compile_s is "
-                         "honestly cold and compile_warm_s warm)")
+                         "(default: <checkout>/.jax_cache; "
+                         "JAX_COMPILATION_CACHE_DIR wins when set)")
     args = ap.parse_args(argv)
     regenerate(tuple(args.topologies), out=args.out,
                cache_dir=args.cache_dir)

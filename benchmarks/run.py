@@ -30,15 +30,19 @@ def main(argv=None):
     ap.add_argument("--devices", type=int, default=None,
                     help="host-platform device count (jax-shard sections)")
     ap.add_argument("--cache-dir", default=None,
-                    help="persistent JAX compilation-cache dir")
+                    help="persistent JAX compilation-cache dir (default: "
+                         "<checkout>/.jax_cache; JAX_COMPILATION_CACHE_DIR "
+                         "wins when set)")
     args = ap.parse_args(argv)
 
     from . import (fig1_critical, fig2_regimes, fig3_traces, kernels_bench,
                    roofline, theory_tables)
+    from repro.core.shard import enable_compile_cache
+
     from .common import configure_scan_runtime, emit
 
-    configure_scan_runtime(devices=args.devices, cache_dir=args.cache_dir,
-                           warn=True)
+    configure_scan_runtime(devices=args.devices, warn=True)
+    enable_compile_cache(args.cache_dir)
 
     t0 = time.time()
     jobs1 = args.jobs or (1_000_000 if args.full else 12_000)

@@ -35,11 +35,13 @@ device side of the substrate:
   1-thread pin that bought 3-4x on the dispatch-bound BS scan is the
   ``devices=1`` special case.  Unlike the old pin, a call that comes too
   late (backend already initialized by someone else) **warns loudly once**
-  instead of silently keeping the default pool.
-* :func:`enable_compile_cache` — persistent JAX compilation cache
-  (``jax_compilation_cache_dir``), so repeated k-sweeps stop paying
-  ``compile_s`` per (k, R, J) cell; ``benchmarks/bench_sim.py`` tracks
-  warm-vs-cold compile separately.
+  instead of silently keeping the default pool.  It configures only a CPU
+  backend (``JAX_PLATFORMS=cpu``); on an accelerator host it touches
+  nothing.
+* :func:`enable_compile_cache` — persistent JAX compilation cache at
+  ``JAX_COMPILATION_CACHE_DIR`` or a fixed in-checkout path, so repeated
+  k-sweeps stop paying ``compile_s`` per (k, R, J) cell;
+  ``benchmarks/bench_sim.py`` tracks warm-vs-cold compile separately.
 
 CPU caveat (measured, 2-core host): XLA:CPU backs all host-platform
 devices of a process with **one shared intra-op thread pool**, so the
@@ -62,13 +64,8 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
+from jax import enable_x64
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:                              # public since jax 0.4.35-ish ...
-    from jax import shard_map
-except ImportError:               # ... experimental before (and removed
-    from jax.experimental.shard_map import shard_map  # there after 0.6)
 
 from . import engines
 from . import failures as flr
@@ -97,6 +94,13 @@ from .sim_jax import (_bs_args, _bs_core, _bs_fail_core,
 from .workload import BatchTrace
 
 _FLAG = "--xla_force_host_platform_device_count"
+
+#: Every sharded body below is strictly per-lane: no collective crosses a
+#: shard.  The scan carries start from constants, which the
+#: varying-manual-axes check types as replicated while the carried-out
+#: values vary over the mesh, so the check would refuse a correct program
+#: and has nothing else to verify here.
+shard_map = partial(jax.shard_map, check_vma=False)
 
 
 # --------------------------------------------------------------------------
@@ -127,14 +131,9 @@ def ensure_host_devices(n: int) -> bool:
     """
     if n < 1:
         raise ValueError(f"need at least one device, got {n}")
-    state = _backends_initialized()
-    if state or state is None:
-        # initialized — or unknowable (every probe API gone): validate
-        # against the real topology rather than guess.  In the unknown
-        # case local_device_count() may itself initialize the backend,
-        # which is still the honest outcome: the flag could no longer be
-        # trusted to apply, and a too-small mesh must raise, not silently
-        # shrink.
+    if _backends_initialized():
+        # too late for the flag: validate against the real topology — a
+        # too-small mesh must raise, not silently shrink
         have = jax.local_device_count()
         if have < n:
             raise RuntimeError(
@@ -203,16 +202,32 @@ def _warn_once(msg: str) -> None:
         warnings.warn(msg, RuntimeWarning, stacklevel=3)
 
 
-def enable_compile_cache(cache_dir: str | os.PathLike) -> str:
-    """Point JAX's persistent compilation cache at ``cache_dir``.
+#: the persistent compilation cache's fixed home inside the checkout
+DEFAULT_CACHE_DIR = os.path.normpath(os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), os.pardir, os.pardir,
+    os.pardir, ".jax_cache"))
 
-    Every executable compiled from here on is written to (and on later
-    runs loaded from) the directory, so a repeated k-sweep pays tracing
-    but not XLA compilation per (k, R, J) cell — ``bench_sim`` reports the
-    warm-vs-cold difference as ``compile_warm_s`` vs ``compile_s``.
-    Callable before or after backend init.
+
+def enable_compile_cache(cache_dir: str | os.PathLike | None = None
+                         ) -> str | None:
+    """Switch JAX's persistent compilation cache on; every entry point
+    calls this once.
+
+    The directory is ``JAX_COMPILATION_CACHE_DIR`` when that is set (no
+    argument overrides it), else ``cache_dir``, else the fixed
+    :data:`DEFAULT_CACHE_DIR` — a fixed path, so a later run hits what an
+    earlier one wrote.  Every executable compiled from here on is written
+    to (and on later runs loaded from) the directory, so a repeated
+    k-sweep pays tracing but not XLA compilation per (k, R, J) cell —
+    ``bench_sim`` reports the warm-vs-cold difference as
+    ``compile_warm_s`` vs ``compile_s``.  Returns the directory, or None
+    when the cache is switched off (``JAX_ENABLE_COMPILATION_CACHE=false``,
+    as the test suite runs).  Callable before or after backend init.
     """
-    cache_dir = os.fspath(cache_dir)
+    if not jax.config.jax_enable_compilation_cache:
+        return None
+    cache_dir = os.fspath(os.environ.get("JAX_COMPILATION_CACHE_DIR")
+                          or cache_dir or DEFAULT_CACHE_DIR)
     os.makedirs(cache_dir, exist_ok=True)  # the cache never mkdirs itself
     jax.config.update("jax_compilation_cache_dir", cache_dir)
     # cache every hit: the scan executables compile fast but recompile often
@@ -221,10 +236,18 @@ def enable_compile_cache(cache_dir: str | os.PathLike) -> str:
     return cache_dir
 
 
+def _cpu_backend() -> bool:
+    """Whether the backend that comes up is XLA:CPU, decided before init
+    from ``jax_platforms`` (``JAX_PLATFORMS``): unset lets JAX pick an
+    accelerator."""
+    plats = {p.strip() for p in (jax.config.jax_platforms or "").split(",")}
+    return plats == {"cpu"}
+
+
 def configure_runtime(devices: int | None = None, intra_op_threads: int = 1,
-                      cache_dir: str | os.PathLike | None = None, *,
-                      warn: bool = True) -> bool:
-    """Device-aware XLA runtime setup — replaces ``pin_single_thread_runtime``.
+                      *, warn: bool = True) -> bool:
+    """Device-aware XLA:CPU runtime setup — replaces
+    ``pin_single_thread_runtime``.
 
     Forces ``devices`` host-platform devices (default: whatever an
     existing ``XLA_FLAGS`` entry requests, else 1) and initializes the
@@ -232,9 +255,13 @@ def configure_runtime(devices: int | None = None, intra_op_threads: int = 1,
     ``devices * intra_op_threads`` CPUs, so PJRT sizes its intra-op pool
     to exactly that many threads — ``intra_op_threads=1`` keeps the
     per-op-dispatch win of the old single-thread pin (3-4x on the BS event
-    scan) per device.  ``cache_dir`` additionally enables the persistent
-    compilation cache (:func:`enable_compile_cache`; applied even when the
-    pool can no longer be pinned).
+    scan) per device.
+
+    Only a CPU backend is configured (``JAX_PLATFORMS=cpu``): any other
+    backend starts its own runtime threads during init, and the pin would
+    confine them for the life of the process (restoring the mask resets
+    only the calling thread).  There the call touches nothing and returns
+    True.
 
     Returns True when the runtime is configured as requested.  When the
     backend was **already initialized** by an earlier JAX call the pool
@@ -248,14 +275,13 @@ def configure_runtime(devices: int | None = None, intra_op_threads: int = 1,
     configured.
     """
     global _configured_devices
-    if cache_dir is not None:
-        enable_compile_cache(cache_dir)
     if devices is None:
         devices = _flag_device_count(os.environ.get("XLA_FLAGS", "")) or 1
     if devices < 1 or intra_op_threads < 1:
         raise ValueError("devices and intra_op_threads must be >= 1")
-    state = _backends_initialized()
-    if state or state is None:
+    if not _cpu_backend():
+        return True
+    if _backends_initialized():
         # subsumed iff a previous call really configured the runtime (the
         # pool was pinned) AND the live topology covers the request — the
         # recorded count can understate reality when an env XLA_FLAGS
@@ -374,12 +400,8 @@ def _srpt_shard_call(arrival, need, service, kk, Q: int, NU: tuple,
     # _srpt_core carries the lane axis natively (per-lane sorts and
     # 1-entry scatters, no cross-lane ops) — each shard runs its slice.
     body = lambda a, n, v, k: _srpt_core(a, n, v, k, Q, NU, sf, k_mult)
-    # check_rep: the walk's while_loop has no shard_map replication rule;
-    # the body is strictly per-lane (no cross-shard ops), so the check is
-    # vacuous here anyway.
     return shard_map(body, mesh=mesh, in_specs=(P("r"),) * 4,
-                     out_specs=(P("r"),) * 7,
-                     check_rep=False)(arrival, need, service, kk)
+                     out_specs=(P("r"),) * 7)(arrival, need, service, kk)
 
 
 # Failure-aware variants: identical scan cores as engine="jax"
@@ -806,9 +828,8 @@ def _srpt_grid_shard_call(carry, arrival, need, service, kk, j_live,
             a1, n1, v1, k1, c1, Q, NU, sf, length, j_live=jl1,
             k_mult=k_mult)
         return jax.vmap(f)(c, a, n, v, k, jl)
-    # check_rep=False: see _srpt_shard_call (per-lane while_loop walk)
     return shard_map(body, mesh=mesh, in_specs=(P("c", "r"),) * 6,
-                     out_specs=(P("c", "r"),) * 4, check_rep=False)(
+                     out_specs=(P("c", "r"),) * 4)(
         carry, arrival, need, service, kk, j_live)
 
 

@@ -24,7 +24,7 @@ of :mod:`repro.core.sim_jax` over a leading replications axis:
   this module registers the vmapped scan cores under ``engine="jax"``,
   :mod:`repro.kernels.msj_scan` registers the fused step kernels under
   ``engine="pallas"`` (one kernel per replication on the Pallas grid;
-  interpret mode off-TPU), and :mod:`repro.core.simulator` registers the
+  interpret mode, CPU only), and :mod:`repro.core.simulator` registers the
   exact event engine under ``engine="python"`` — all behind the same
   ``engines.simulate(policy, batch, engine=...)`` entry point.  The
   engines are pinned bit-for-bit against each other in
@@ -47,7 +47,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 from . import engines
 from . import failures as flr
@@ -75,32 +75,12 @@ def _call(fn, *args):
         return jax.block_until_ready(fn(*args))
 
 
-def _backends_initialized() -> bool | None:
+def _backends_initialized() -> bool:
     """Whether any XLA backend has already been created, without creating
-    one.
-
-    Tries, in order: the public predicate (``jax.extend.backend``, present
-    in newer jax releases), the semi-private ``xla_bridge`` predicate, and
-    the raw ``_backends`` registry dict.  Returns ``None`` when every
-    probe is gone (API moved again) — callers must then assume the worst.
-    """
-    def _public():
-        # public API first (jax >= 0.5 exposes the predicate here).
-        # jax.extend is a lazy submodule — import it, don't getattr it.
-        import jax.extend.backend as jexb
-        return jexb.backends_are_initialized()
-
-    probes = (
-        _public,
-        lambda: jax._src.xla_bridge.backends_are_initialized(),
-        lambda: bool(jax._src.xla_bridge._backends),
-    )
-    for probe in probes:
-        try:
-            return bool(probe())
-        except (AttributeError, ImportError):
-            continue
-    return None
+    one.  JAX 0.9 has no public predicate (``jax.extend.backend`` lacks
+    one), so this reads ``xla_bridge``'s."""
+    from jax._src import xla_bridge
+    return bool(xla_bridge.backends_are_initialized())
 
 
 def pin_single_thread_runtime() -> bool:
@@ -1257,7 +1237,7 @@ def sweep_many_server(wl_factory: Callable[..., Workload], points: Sequence,
     ``"jax-shard"`` (device-mesh sharding — see :mod:`repro.core.shard`;
     use ``configure_runtime(devices=N)`` before the first JAX call to
     expose N host devices), ``"pallas"`` (fused step kernels, interpret
-    mode off-TPU — bit-identical, slower on CPU), or ``"python"`` (the
+    mode on the CPU only — bit-identical, slower), or ``"python"`` (the
     exact event engine — slow, but the same interface).  Any ``(policy,
     engine)`` registry pair sweeps; unknown policies raise ``KeyError``.
     Returns mean/CI arrays [policies, points].

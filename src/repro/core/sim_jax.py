@@ -74,7 +74,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 from . import engines
 from .partition import BalancedPartition, balanced_partition
@@ -1366,12 +1366,16 @@ def _srpt_init(R: int, Q: int, dt):
 # boolean unsort scatter second — ScatterExpander serializes it into a
 # Q·R-trip while loop.  The step below is bit-identical to the reference
 # (pinned in tests/test_sim_cross.py) but restructures every hot op into
-# single-operand u32/u64 pack sorts over composite integer keys:
+# single-operand sorts, most of them u32/u64 packs of composite integer
+# keys:
 #
-# * Rank keys are nonnegative f64 (or +inf empty sentinels), so their
-#   IEEE-754 bit patterns order identically as u64 — one bitcast single-
-#   operand sort + a branchless bisection turns the (rank, arrival) sort
-#   into collapsed integer ranks.
+# * Rank keys are nonnegative f64 (or +inf empty sentinels): one single-
+#   operand sort of the keys + a branchless bisection turns the (rank,
+#   arrival) sort into collapsed integer ranks.  The keys are sorted as
+#   floats, not as u64 bit patterns: the TPU's f64 emulation refuses a
+#   bitcast to u64, and float order ties -0.0 with +0.0 exactly as the
+#   reference sort and the python oracle do (bit order would put -0.0
+#   after +inf; only a -0.0 service time could produce one).
 # * Tie-break arrival times are replaced by dense per-lane arrival *ranks*
 #   (a one-time cummax over the sorted trace), preserving every equality
 #   class, so the composite (rank, arrival-rank, slot) key packs into one
@@ -1582,12 +1586,11 @@ def _srpt_fast_make_step(jobrec, kk, Q: int, NU: tuple, sf: bool,
             run, jnp.maximum(0.0, rem - (t[:, None] - rs)), rem)
         rank = cur_rem * need.astype(dt) if sf else cur_rem
         rk = jnp.where(occ, rank, INF)
-        # nonnegative f64 bit patterns order as u64: single-operand sort
-        # + bisection collapses ranks to integers, then one pack sort on
-        # (rank', arrival rank, slot) yields the stable permutation
-        rkb = jax.lax.bitcast_convert_type(rk, jnp.uint64)
-        srt = jax.lax.sort((rkb,), dimension=1, num_keys=1)[0]
-        r1 = bsearch(srt, rkb)
+        # single-operand sort of the rank keys + bisection collapses them
+        # to integers, then one pack sort on (rank', arrival rank, slot)
+        # yields the stable permutation
+        srt = jax.lax.sort((rk,), dimension=1, num_keys=1)[0]
+        r1 = bsearch(srt, rk)
         abi = abr.astype(packdt)
         pack = ((r1.astype(packdt) << (bJ + bQ)) | (abi << bQ)
                 | iota_u.astype(packdt))

@@ -19,11 +19,11 @@ are pinned rtol=0 against the jax-batch engines in
 
 Execution modes: ``interpret=True`` (the CPU/CI path — the grid is scanned
 by the Pallas interpreter, one replication at a time, so it fuses nothing on
-CPU and exists for correctness + the TPU-less benchmark rows); on a TPU
-backend ``interpret=False`` compiles the step loop on-core.  The TPU path
-requires f32 state (no f64 on TPU) and per-replication blocks resident in
-VMEM (J · 8 bytes per input row), neither of which this CPU-only repo can
-exercise — ``ops.py`` auto-selects interpret mode off-TPU.
+CPU and exists for correctness + the CPU benchmark rows).  ``interpret=False``
+does not lower for the TPU: the ``(1, J)`` blocks break Mosaic's (8, 128)
+tiling rule and the state is f64.  A TPU path needs f32 state, replications
+on the lane axis and J streamed through VMEM (ROADMAP S7) — ``ops.py``
+refuses every backend other than the CPU until then.
 
 Inputs are [R, J] trace arrays plus the eq.-2 partition's ``slots`` vector
 ([C], replicated to every grid cell — Pallas kernels cannot capture array

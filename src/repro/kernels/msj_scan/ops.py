@@ -1,11 +1,12 @@
 """Public wrappers for the fused event-scan kernels.
 
-Pallas compilation on TPU, interpret mode everywhere else (the repo's CPU
-CI path): interpret mode scans the grid one replication at a time with the
-kernel body executed as ordinary XLA ops, so it fuses nothing on CPU — it
-exists for bit-level cross-validation and the ``engine="pallas"`` benchmark
-rows, not CPU speed.  See ``kernel.py`` for the TPU-path constraints
-(f32-only state, per-replication rows resident in VMEM).
+The kernels run only in interpret mode on the CPU backend: interpret mode
+scans the grid one replication at a time with the kernel body executed as
+ordinary XLA ops, so it fuses nothing — it exists for bit-level
+cross-validation and the ``engine="pallas"`` benchmark rows, not speed.
+They do not lower for the TPU yet: their ``(1, J)`` blocks break Mosaic's
+(8, 128) tiling rule and their state is f64 (see ``kernel.py``).  So every
+other backend is refused at dispatch, before anything is compiled.
 
 This module also registers the kernels as the ``engine="pallas"`` cores of
 the :mod:`repro.core.engines` registry — the cores reuse the input-prep and
@@ -21,7 +22,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 from repro.core import engines
 from repro.core import failures as flr
@@ -40,7 +41,14 @@ from .srpt import srpt_scan_fwd
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """True on the CPU backend; any other backend is refused."""
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise NotImplementedError(
+            f'engine="pallas" runs only in interpret mode on the CPU '
+            f'backend, not on {backend!r}: the kernels do not lower for the '
+            f'TPU yet (ROADMAP.md S7/D3). Use engine="jax" there.')
+    return True
 
 
 def fcfs_scan(arrival, need, service, *, k: int):
@@ -70,8 +78,6 @@ def srpt_scan(arrival, need, service, kk, *, Q: int, NU: tuple, sf: bool):
     (job_ev, t_ev, fs_ev, ovf, npre, ne, peak)."""
     return srpt_scan_fwd(arrival, need, service, kk, Q=Q, NU=NU, sf=sf,
                          interpret=_interpret())
-
-
 
 
 # -- engine="pallas" registry cores -----------------------------------------

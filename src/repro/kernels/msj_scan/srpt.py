@@ -17,7 +17,7 @@ sub-steps.
 
 Grid layout matches the other kernels: one replication per Pallas grid
 cell, the whole 2J-event loop as an in-kernel ``fori_loop``, interpret
-mode off-TPU (see ``ops.py``).  ``Q`` must be a power of two — guaranteed
+mode on the CPU only (see ``ops.py``).  ``Q`` must be a power of two — guaranteed
 by ``_srpt_args``, which rounds the slot-table capacity up (the bitonic
 network and the fast path's slot-index pack keys both need it).
 """

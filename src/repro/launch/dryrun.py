@@ -91,7 +91,7 @@ def lower_cell(arch: str, shape: ShapeCell, mesh, rules=DEFAULT_RULES):
             _shardings(train_inputs(cfg, shape.batch, shape.seq, "axes"),
                        batch, mesh),
         )
-        with mesh:
+        with jax.set_mesh(mesh):
             lowered = jax.jit(step, in_shardings=in_sh).lower(p, opt, batch)
         return lowered, {"microbatches": M}
     if shape.kind == "prefill":
@@ -103,7 +103,7 @@ def lower_cell(arch: str, shape: ShapeCell, mesh, rules=DEFAULT_RULES):
                        batch, mesh),
         )
         fn = lambda params, b: model.prefill(params, b)  # noqa: E731
-        with mesh:
+        with jax.set_mesh(mesh):
             lowered = jax.jit(fn, in_shardings=in_sh).lower(p, batch)
         return lowered, {}
     # decode — SERVING rules (§Perf C1): ZeRO-3 fsdp sharding is a training
@@ -128,7 +128,7 @@ def lower_cell(arch: str, shape: ShapeCell, mesh, rules=DEFAULT_RULES):
         None,
     )
     fn = lambda params, c, t, i: model.decode_step(params, c, t, i)  # noqa
-    with mesh:
+    with jax.set_mesh(mesh):
         lowered = jax.jit(fn, in_shardings=in_sh).lower(p, caches, tok, pos)
     return lowered, {}
 

@@ -46,6 +46,7 @@ import numpy as np
 from repro.configs import get_config
 from repro.core import engines
 from repro.core.partition import balanced_partition
+from repro.core.shard import enable_compile_cache
 from repro.core.theory import analyze
 from repro.core.workload import DiurnalSource, Exp, JobClass, Workload
 from repro.sched.cluster import BalancedMeshPartition
@@ -206,6 +207,7 @@ def main(argv=None):
                     help="additionally run N requests through "
                          "prefill/decode (reduced configs on CPU)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     classes = default_classes(args.fleet)
     run_epochs(classes, fleet=args.fleet, epochs=args.epochs,

@@ -189,7 +189,6 @@ def _moe_ffn_shard_map(x, params, cfg: ArchConfig, mesh):
     lowering of the scatter.
     """
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     m = cfg.moe
     B, S, D = x.shape
@@ -256,13 +255,13 @@ def _moe_ffn_shard_map(x, params, cfg: ArchConfig, mesh):
 
     xf = x.reshape(B * S, D)
     batch_spec = P(data_axes if data_axes else None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(batch_spec, P(None, None),
                   P("model", data_axes, None), P("model", data_axes, None),
                   P("model", None, data_axes)),
         out_specs=(batch_spec, P()),
-        check_rep=False)
+        check_vma=False)
     out, aux = fn(xf, params["router"], params["w_gate"], params["w_up"],
                   params["w_down"])
     aux = jnp.mean(aux)

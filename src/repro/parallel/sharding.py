@@ -27,7 +27,7 @@ import dataclasses
 from typing import Any, Mapping, Sequence
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AbstractMesh, Mesh, NamedSharding, PartitionSpec as P
 
 
 LogicalAxes = tuple[str | None, ...]
@@ -108,13 +108,9 @@ def shard_constraint(x, rules: AxisRules, axes: Sequence[str | None],
         x, NamedSharding(mesh, sized_spec(rules, axes, x.shape, mesh)))
 
 
-def _current_mesh() -> Mesh | None:
-    try:
-        from jax.interpreters.pxla import thread_resources
-        env = thread_resources.env
-        return env.physical_mesh
-    except Exception:  # pragma: no cover
-        return None
+def _current_mesh() -> AbstractMesh:
+    """The mesh set by ``jax.set_mesh`` (empty outside one)."""
+    return jax.sharding.get_abstract_mesh()
 
 
 def tree_specs(axes_tree, rules: AxisRules, mesh: Mesh):

@@ -90,7 +90,7 @@ class Trainer:
     def init_state(self, rng=None):
         rng = rng if rng is not None else jax.random.PRNGKey(self.seed)
         p_sh, o_sh, _ = self._shardings()
-        with self.mesh:
+        with jax.set_mesh(self.mesh):
             params = jax.jit(self.model.init, out_shardings=p_sh)(rng)
             opt = jax.jit(adamw_init, out_shardings=o_sh)(params)
         return params, opt
@@ -131,7 +131,7 @@ class Trainer:
                 continue
             if step >= num_steps:       # num_steps = TOTAL training steps
                 break
-            with self.mesh:
+            with jax.set_mesh(self.mesh):
                 batch = {k: jax.device_put(v, b_sh[k])
                          for k, v in batch.items()}
                 params, opt_state, metrics = jstep(params, opt_state, batch)

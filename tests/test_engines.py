@@ -70,6 +70,23 @@ def test_registry_loud_errors():
         engines.register("fcfs", "jax")(lambda batch, **kw: None)
 
 
+@pytest.mark.parametrize("policy", ("fcfs", "modbs-fcfs", "bs-fcfs",
+                                    "sf-srpt", "ff-srpt"))
+def test_pallas_refused_off_the_cpu_at_dispatch(policy, monkeypatch):
+    """The kernels do not lower for the TPU: a non-CPU backend gets a
+    NotImplementedError naming the roadmap items at dispatch, never a
+    Mosaic error and never interpret mode on the accelerator."""
+    import jax
+    wl = small_workload()
+    batch = wl.sample_traces(20, 1, seed=0)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(NotImplementedError, match="S7/D3"):
+        engines.simulate(policy, batch, engine="pallas", wl=wl)
+    with pytest.raises(NotImplementedError, match="CPU"):
+        engines.simulate_grid(policy, [engines.GridCell(batch, wl=wl)],
+                              engine="pallas")
+
+
 def test_python_cores_require_workload_for_bsf():
     wl = small_workload()
     batch = wl.sample_traces(50, 1, seed=0)

@@ -101,15 +101,76 @@ def test_configure_runtime_rejects_bad_args():
         configure_runtime(devices=1, intra_op_threads=0)
 
 
-def test_enable_compile_cache_creates_dir_and_sets_config(tmp_path):
-    old = jax.config.jax_compilation_cache_dir
+def test_configure_runtime_leaves_affinity_alone_off_cpu(monkeypatch):
+    """On an accelerator host the pin would confine the runtime's own
+    threads for good: configure_runtime must not touch the affinity (nor
+    the host-device flag) when the platform is not the CPU."""
+    calls = []
+    monkeypatch.setattr(os, "sched_setaffinity",
+                        lambda *a: calls.append(a))
+    monkeypatch.setattr(shard, "_backends_initialized", lambda: False)
+    monkeypatch.setattr(shard, "_configured_devices", None)
+    monkeypatch.setenv("XLA_FLAGS", "")
+    old = jax.config.jax_platforms
     try:
-        target = tmp_path / "cache" / "nested"
-        got = enable_compile_cache(target)
-        assert got == str(target) and target.is_dir()
-        assert jax.config.jax_compilation_cache_dir == str(target)
+        for plats in ("tpu", "tpu,cpu", ""):
+            jax.config.update("jax_platforms", plats)
+            assert configure_runtime(devices=2) is True
+        assert calls == [] and os.environ["XLA_FLAGS"] == ""
+        # the CPU backend is still pinned, and restored after init
+        jax.config.update("jax_platforms", "cpu")
+        assert configure_runtime(devices=2) is True
+        assert len(calls) == 2 and calls[-1][1] == os.sched_getaffinity(0)
+        assert _flag_device_count(os.environ["XLA_FLAGS"]) == 2
     finally:
+        jax.config.update("jax_platforms", old)
+
+
+@pytest.fixture
+def compile_cache_on(monkeypatch):
+    """The persistent cache switched on for one test (the suite runs with
+    it off), restored afterwards."""
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    old = jax.config.jax_compilation_cache_dir
+    jax.config.update("jax_enable_compilation_cache", True)
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", False)
         jax.config.update("jax_compilation_cache_dir", old)
+
+
+def test_enable_compile_cache_creates_dir_and_sets_config(tmp_path,
+                                                          compile_cache_on):
+    target = tmp_path / "cache" / "nested"
+    got = enable_compile_cache(target)
+    assert got == str(target) and target.is_dir()
+    assert jax.config.jax_compilation_cache_dir == str(target)
+
+
+def test_enable_compile_cache_env_var_wins(tmp_path, monkeypatch,
+                                           compile_cache_on):
+    env_dir = tmp_path / "from_env"
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(env_dir))
+    assert enable_compile_cache(tmp_path / "from_arg") == str(env_dir)
+    assert jax.config.jax_compilation_cache_dir == str(env_dir)
+    assert env_dir.is_dir() and not (tmp_path / "from_arg").exists()
+
+
+def test_enable_compile_cache_defaults_to_fixed_checkout_path(
+        tmp_path, monkeypatch, compile_cache_on):
+    assert shard.DEFAULT_CACHE_DIR == os.path.join(REPO_ROOT, ".jax_cache")
+    monkeypatch.setattr(shard, "DEFAULT_CACHE_DIR", str(tmp_path / "fixed"))
+    assert enable_compile_cache() == str(tmp_path / "fixed")
+    assert jax.config.jax_compilation_cache_dir == str(tmp_path / "fixed")
+
+
+def test_enable_compile_cache_stays_off_when_switched_off(tmp_path):
+    assert not jax.config.jax_enable_compilation_cache  # the suite's state
+    old = jax.config.jax_compilation_cache_dir
+    assert enable_compile_cache(tmp_path / "c") is None
+    assert jax.config.jax_compilation_cache_dir == old
+    assert not (tmp_path / "c").exists()
 
 
 # -- padding / mesh helpers ----------------------------------------------------

@@ -139,8 +139,8 @@ def test_backends_initialized_probe_agrees_with_reality():
     from repro.core.sim_batch import _backends_initialized
 
     jax.devices()
-    # after init the probe must say so (None = every probe API is gone,
-    # which would silently disable pinning — fail loudly here instead)
+    # after init the probe must say so (a probe that lost its API would
+    # raise here instead of silently disabling the pin)
     assert _backends_initialized() is True
 
 

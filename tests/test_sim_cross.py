@@ -20,7 +20,7 @@ import pytest
 from _hypothesis_compat import given, settings, st
 
 import jax.numpy as jnp
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 from repro.core import sim_jax
 from repro.core.policies import make_policy
