@@ -122,9 +122,14 @@ resume forward, new runs pay one compile per policy.
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import dataclasses
 import importlib
+import itertools
 from typing import TYPE_CHECKING, Callable, Sequence
+
+from . import spans
 
 if TYPE_CHECKING:  # pragma: no cover - typing only, avoids import cycles
     from .sim_batch import BatchSimResult
@@ -154,6 +159,12 @@ _GRID_REGISTRY: dict[tuple[str, str], Callable] = {}
 #: (``failures=``): 'python' kills in-flight jobs, the scan/kernel engines
 #: drain capacity — iterated by ``tests/test_failures.py``
 FAILURE_ENGINES = ("python", "jax", "jax-shard", "pallas")
+
+#: ids of this process's :func:`simulate` calls, the ``call`` of their spans
+_CALL_IDS = itertools.count(1)
+#: the id of the :func:`simulate` call running now (None outside one)
+_CALL: contextvars.ContextVar[int | None] = contextvars.ContextVar(
+    "repro_call", default=None)
 
 #: short benchmark-CLI aliases -> canonical policy names (Policy.name)
 ALIASES = {
@@ -361,13 +372,32 @@ def simulate(policy: str, batch: "BatchTrace", *, engine: str = "jax",
     pair to the python event oracle instead of raising, announcing the
     substitution with a once-per-process ``RuntimeWarning``
     (:func:`warn_fallback`) — never silently.
+
+    Each call is one ``repro.simulate`` host span (:mod:`repro.core.spans`)
+    with a per-process ``call`` id; the batch helpers the cores share open
+    its ``repro.prep``/``run``/``fetch``/``assemble`` spans.
     """
     engine = _resolve_fallback(policy, engine, fallback)
     core = get(policy, engine)
     fb = kw.get("failures")
-    validate_batch(batch, partition=partition,
-                   failures=fb if hasattr(fb, "k") else None)
-    return core(batch, partition=partition, wl=wl, **kw)
+    n = next(_CALL_IDS)
+    token = _CALL.set(n)
+    try:
+        with spans.span("repro.simulate", call=n, policy=canonical(policy),
+                        engine=engine):
+            with call_span("repro.prep"):
+                validate_batch(batch, partition=partition,
+                               failures=fb if hasattr(fb, "k") else None)
+            return core(batch, partition=partition, wl=wl, **kw)
+    finally:
+        _CALL.reset(token)
+
+
+def call_span(name: str):
+    """Span ``name`` of the :func:`simulate` call running now, carrying its
+    ``call`` id; no span outside a call (the grid and stream paths)."""
+    n = _CALL.get()
+    return contextlib.nullcontext() if n is None else spans.span(name, call=n)
 
 
 def stream_registered() -> tuple[tuple[str, str], ...]:

@@ -40,8 +40,7 @@ device side of the substrate:
   nothing.
 * :func:`enable_compile_cache` — persistent JAX compilation cache at
   ``JAX_COMPILATION_CACHE_DIR`` or a fixed in-checkout path, so repeated
-  k-sweeps stop paying ``compile_s`` per (k, R, J) cell;
-  ``benchmarks/bench_sim.py`` tracks warm-vs-cold compile separately.
+  k-sweeps stop paying XLA compilation per (k, R, J) cell.
 
 CPU caveat (measured, 2-core host): XLA:CPU backs all host-platform
 devices of a process with **one shared intra-op thread pool**, so the
@@ -77,14 +76,14 @@ from .sim_batch import (_backends_initialized, _bs_fail_args,
                         _class_inputs, _dev, _fcfs_fail_grid_extract,
                         _fcfs_fail_grid_plan, _fcfs_grid_extract,
                         _fcfs_grid_plan, _fcfs_inputs, _fcfs_result,
-                        _fcfs_stream_init, _merged_fcfs_inputs,
+                        _fcfs_stream_init, _fetch, _merged_fcfs_inputs,
                         _modbs_fail_grid_extract, _modbs_fail_grid_plan,
                         _modbs_grid_extract, _modbs_grid_plan, _modbs_result,
-                        _modbs_stream_init, _partition_args, _scan_stream,
-                        _slice_stream_result, _srpt_grid_carry,
-                        _srpt_grid_extract, _srpt_grid_plan, _srpt_k_mult,
-                        _srpt_no_failures, _srpt_nu, _srpt_result,
-                        _stream_partition, _with_drain_obs)
+                        _modbs_stream_init, _partition_args, _puts,
+                        _scan_stream, _slice_stream_result, _srpt_grid_carry,
+                        _srpt_grid_extract, _srpt_grid_plan, _srpt_inputs,
+                        _srpt_k_mult, _srpt_no_failures, _srpt_nu,
+                        _srpt_result, _stream_partition, _with_drain_obs)
 from .sim_jax import (_bs_args, _bs_core, _bs_fail_core,
                       _bs_fail_stream_core, _bs_stream_core, _fcfs_core,
                       _fcfs_fail_core, _fcfs_fail_stream_core,
@@ -218,9 +217,8 @@ def enable_compile_cache(cache_dir: str | os.PathLike | None = None
     :data:`DEFAULT_CACHE_DIR` — a fixed path, so a later run hits what an
     earlier one wrote.  Every executable compiled from here on is written
     to (and on later runs loaded from) the directory, so a repeated
-    k-sweep pays tracing but not XLA compilation per (k, R, J) cell —
-    ``bench_sim`` reports the warm-vs-cold difference as
-    ``compile_warm_s`` vs ``compile_s``.  Returns the directory, or None
+    k-sweep pays tracing but not XLA compilation per (k, R, J) cell.
+    Returns the directory, or None
     when the cache is switched off (``JAX_ENABLE_COMPILATION_CACHE=false``,
     as the test suite runs).  Callable before or after backend init.
     """
@@ -457,20 +455,20 @@ def _fcfs_jax_shard(batch, *, partition=None, wl=None, devices=None,
     if failures is None:
         padded, R = _pad_batch(batch, mesh.size)
         with enable_x64():
-            starts = _call(_fcfs_shard_call, *_fcfs_inputs(padded), batch.k,
-                           mesh)
-        return _fcfs_result(batch, np.asarray(starts)[:R])
+            starts = _fetch(_call(_fcfs_shard_call, *_fcfs_inputs(padded),
+                                  batch.k, mesh), R)
+        return _fcfs_result(batch, starts)
     flr.require_drain(failures, "jax-shard")
     ms = _merged_fcfs_inputs(batch, failures)
     (t, n, svc, t_up, isf), R = _pad_reps(mesh.size, ms.t, ms.need,
                                           ms.service, ms.t_up, ms.is_fail)
     with enable_x64():
-        starts_m = _call(_fcfs_fail_shard_call, jnp.asarray(t, jnp.float64),
-                         jnp.asarray(n, jnp.int32),
-                         jnp.asarray(svc, jnp.float64),
-                         jnp.asarray(t_up, jnp.float64),
-                         jnp.asarray(isf != 0), batch.k, mesh)
-    starts = np.take_along_axis(np.asarray(starts_m)[:R], ms.job_pos, axis=1)
+        starts_m = _fetch(_call(
+            _fcfs_fail_shard_call,
+            *_puts((t, jnp.float64), (n, jnp.int32), (svc, jnp.float64),
+                   (t_up, jnp.float64), (isf != 0, jnp.bool_)),
+            batch.k, mesh), R)
+    starts = np.take_along_axis(starts_m, ms.job_pos, axis=1)
     return _with_drain_obs(_fcfs_result(batch, starts), batch, failures)
 
 
@@ -483,10 +481,10 @@ def _modbs_jax_shard(batch, *, partition=None, wl=None, devices=None,
     if failures is None:
         padded, R = _pad_batch(batch, mesh.size)
         with enable_x64():
-            blocked, starts = _call(_modbs_shard_call, *_class_inputs(padded),
-                                    jnp.asarray(slots), s_max, h, mesh)
-        return _modbs_result(batch, np.asarray(blocked)[:R],
-                             np.asarray(starts)[:R])
+            blocked, starts = _fetch(_call(
+                _modbs_shard_call, *_class_inputs(padded), jnp.asarray(slots),
+                s_max, h, mesh), R)
+        return _modbs_result(batch, blocked, starts)
     flr.require_drain(failures, "jax-shard")
     part = partition if partition is not None else balanced_partition(wl)
     ft, ftgt, fup, count = flr.partition_targets(failures, part)
@@ -495,14 +493,14 @@ def _modbs_jax_shard(batch, *, partition=None, wl=None, devices=None,
     (t, c, n, svc, t_up, isf), R = _pad_reps(
         mesh.size, ms.t, ms.cls, ms.need, ms.service, ms.t_up, ms.is_fail)
     with enable_x64():
-        blocked_m, starts_m = _call(
-            _modbs_fail_shard_call, jnp.asarray(t, jnp.float64),
-            jnp.asarray(c, jnp.int32), jnp.asarray(n, jnp.int32),
-            jnp.asarray(svc, jnp.float64), jnp.asarray(t_up, jnp.float64),
-            jnp.asarray(isf != 0), jnp.asarray(slots), s_max, h, mesh)
-    starts = np.take_along_axis(np.asarray(starts_m)[:R], ms.job_pos, axis=1)
-    blocked = np.take_along_axis(np.asarray(blocked_m)[:R], ms.job_pos,
-                                 axis=1)
+        blocked_m, starts_m = _fetch(_call(
+            _modbs_fail_shard_call,
+            *_puts((t, jnp.float64), (c, jnp.int32), (n, jnp.int32),
+                   (svc, jnp.float64), (t_up, jnp.float64),
+                   (isf != 0, jnp.bool_)),
+            jnp.asarray(slots), s_max, h, mesh), R)
+    starts = np.take_along_axis(starts_m, ms.job_pos, axis=1)
+    blocked = np.take_along_axis(blocked_m, ms.job_pos, axis=1)
     return _with_drain_obs(_modbs_result(batch, blocked, starts), batch,
                            failures)
 
@@ -516,24 +514,21 @@ def _bs_jax_shard(batch, *, partition=None, wl=None, queue_cap=None,
     if failures is None:
         padded, R = _pad_batch(batch, mesh.size)
         with enable_x64():
-            tagged, rec_t, ovf = _call(_bs_shard_call, *_class_inputs(padded),
-                                       jnp.asarray(slots), s_max, h, q_cap,
-                                       mesh)
-        return _bs_result(batch, np.asarray(tagged)[:R],
-                          np.asarray(rec_t)[:R], np.asarray(ovf)[:R], q_cap)
+            tagged, rec_t, ovf = _fetch(_call(
+                _bs_shard_call, *_class_inputs(padded), jnp.asarray(slots),
+                s_max, h, q_cap, mesh), R)
+        return _bs_result(batch, tagged, rec_t, ovf, q_cap)
     flr.require_drain(failures, "jax-shard")
     ft, ftgt, fup, length = _bs_fail_args(batch, failures, partition, wl)
     padded, R = _pad_batch(batch, mesh.size)
     (ft, ftgt, fup), _ = _pad_reps(mesh.size, ft, ftgt, fup)
     with enable_x64():
-        tagged, rec_t, ovf = _call(
+        tagged, rec_t, ovf = _fetch(_call(
             _bs_fail_shard_call, *_class_inputs(padded),
-            jnp.asarray(ft, jnp.float64), jnp.asarray(ftgt, jnp.int32),
-            jnp.asarray(fup, jnp.float64), jnp.asarray(slots), s_max, h,
-            q_cap, length, mesh)
-    return _with_drain_obs(
-        _bs_result(batch, np.asarray(tagged)[:R], np.asarray(rec_t)[:R],
-                   np.asarray(ovf)[:R], q_cap), batch, failures)
+            *_puts((ft, jnp.float64), (ftgt, jnp.int32), (fup, jnp.float64)),
+            jnp.asarray(slots), s_max, h, q_cap, length, mesh), R)
+    return _with_drain_obs(_bs_result(batch, tagged, rec_t, ovf, q_cap),
+                           batch, failures)
 
 
 def _srpt_jax_shard(sf: bool, batch, *, partition=None, wl=None,
@@ -545,18 +540,11 @@ def _srpt_jax_shard(sf: bool, batch, *, partition=None, wl=None,
     mesh = local_mesh(devices)
     padded, R = _pad_batch(batch, mesh.size)
     with enable_x64():
-        job_ev, t_ev, fs_ev, ovf, npre, ne, peak = _call(
-            _srpt_shard_call,
-            _dev(padded.arrival, jnp.float64),
-            _dev(padded.need, jnp.float64),
-            _dev(padded.service, jnp.float64),
-            _dev(np.full(padded.reps, float(batch.k)), jnp.float64),
-            q_cap, NU, sf, _srpt_k_mult(NU, batch), mesh)
-    return _srpt_result(batch, np.asarray(job_ev)[:R],
-                        np.asarray(t_ev)[:R], np.asarray(fs_ev)[:R],
-                        np.asarray(ovf)[:R], np.asarray(npre)[:R],
-                        np.asarray(ne)[:R], q_cap,
-                        peak=np.asarray(peak)[:R])
+        job_ev, t_ev, fs_ev, ovf, npre, ne, peak = _fetch(_call(
+            _srpt_shard_call, *_srpt_inputs(padded),
+            q_cap, NU, sf, _srpt_k_mult(NU, batch), mesh), R)
+    return _srpt_result(batch, job_ev, t_ev, fs_ev, ovf, npre, ne, q_cap,
+                        peak=peak)
 
 
 @engines.register("sf-srpt", "jax-shard")

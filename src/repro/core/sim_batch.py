@@ -51,6 +51,7 @@ from jax import enable_x64
 
 from . import engines
 from . import failures as flr
+from . import spans
 from .partition import BalancedPartition, balanced_partition
 from .sim_jax import (_BIG, _SRPT_COLS, _bs_args, _bs_core, _bs_fail_core,
                       _bs_fail_stream_core, _bs_scatter_events,
@@ -68,11 +69,27 @@ WAIT_EPS = 1e-9
 
 def _call(fn, *args):
     """Run a jitted call to completion, silencing the donation no-op warning
-    XLA emits on backends (CPU) that cannot alias the donated buffers."""
-    with warnings.catch_warnings():
+    XLA emits on backends (CPU) that cannot alias the donated buffers.
+    The outputs stay on the device; inside a ``simulate`` call this is its
+    ``repro.run`` span."""
+    with engines.call_span("repro.run"), warnings.catch_warnings():
         warnings.filterwarnings(
             "ignore", message="Some donated buffers were not usable")
         return jax.block_until_ready(fn(*args))
+
+
+def _fetch(out, reps: int | None = None):
+    """The outputs of :func:`_call` on the host, in one transfer (the
+    ``repro.fetch`` span; their bytes count to ``fetch_bytes``), cut back
+    to the first ``reps`` replications when the sharded engines padded
+    them."""
+    with engines.call_span("repro.fetch"):
+        host = jax.device_get(out)
+        spans.add("fetch_bytes",
+                  sum(a.nbytes for a in jax.tree_util.tree_leaves(host)))
+    if reps is None:
+        return host
+    return jax.tree_util.tree_map(lambda a: a[:reps], host)
 
 
 def _backends_initialized() -> bool:
@@ -236,6 +253,14 @@ def _dev(x, dtype) -> jnp.ndarray:
     return jax.device_put(np.array(x, dtype))
 
 
+def _puts(*pairs) -> tuple:
+    """:func:`_dev` of each ``(array, dtype)`` pair, on the device when
+    this returns: the transfer ends a call's ``repro.prep`` span rather
+    than running into ``repro.run``."""
+    with engines.call_span("repro.prep"):
+        return jax.block_until_ready(tuple(_dev(x, dt) for x, dt in pairs))
+
+
 def loss_queue_sim_batch(arrival: np.ndarray, service: np.ndarray,
                          s: int) -> BatchSimResult:
     """Batched M/GI/s/s: [R, J] arrival/service arrays, R independent paths."""
@@ -255,49 +280,62 @@ def loss_queue_sim_batch(arrival: np.ndarray, service: np.ndarray,
 
 def _fcfs_inputs(batch: BatchTrace) -> tuple:
     """(arrival f64, need i32, service f64) device arrays of a batch."""
-    return (_dev(batch.arrival, jnp.float64),
-            _dev(batch.need, jnp.int32),
-            _dev(batch.service, jnp.float64))
+    return _puts((batch.arrival, jnp.float64), (batch.need, jnp.int32),
+                 (batch.service, jnp.float64))
 
 
 def _class_inputs(batch: BatchTrace) -> tuple:
     """(arrival f64, cls i32, need i32, service f64) device arrays."""
-    return (_dev(batch.arrival, jnp.float64),
-            _dev(batch.cls, jnp.int32),
-            _dev(batch.need, jnp.int32),
-            _dev(batch.service, jnp.float64))
+    return _puts((batch.arrival, jnp.float64), (batch.cls, jnp.int32),
+                 (batch.need, jnp.int32), (batch.service, jnp.float64))
+
+
+def _srpt_inputs(batch: BatchTrace) -> tuple:
+    """(arrival, need, service, k per lane) f64 device arrays of an SRPT
+    scan."""
+    return _puts((batch.arrival, jnp.float64), (batch.need, jnp.float64),
+                 (batch.service, jnp.float64),
+                 (np.full(batch.reps, float(batch.k)), jnp.float64))
 
 
 def _partition_args(batch: BatchTrace, partition: BalancedPartition | None,
                     wl: Workload | None) -> tuple[np.ndarray, int, int]:
     """(slots, s_max, h) of the eq.-2 partition, validated for the batch."""
-    if partition is None:
-        if wl is None:
-            raise ValueError("need a partition or a workload")
-        partition = balanced_partition(wl)
-    slots = np.asarray(partition.slots, dtype=np.int32)
-    s_max = int(slots.max())
-    h = int(partition.helpers)
-    if h < int(batch.need.max()):
-        raise ValueError("helper set smaller than the largest server need")
+    with engines.call_span("repro.prep"):
+        if partition is None:
+            if wl is None:
+                raise ValueError("need a partition or a workload")
+            partition = balanced_partition(wl)
+        slots = np.asarray(partition.slots, dtype=np.int32)
+        s_max = int(slots.max())
+        h = int(partition.helpers)
+        if h < int(batch.need.max()):
+            raise ValueError("helper set smaller than the largest server need")
     return slots, s_max, h
+
+
+# The ``_*_result`` helpers, ``_with_drain_obs`` included, are a call's
+# ``repro.assemble`` span: overflow checks, event-to-job scatters and the
+# BatchSimResult, from outputs already on the host (``_fetch``).
 
 
 def _fcfs_result(batch: BatchTrace, starts) -> BatchSimResult:
     # same op order as the single-trace path so replications bit-match it
-    starts = np.asarray(starts)
-    return BatchSimResult(response=starts + batch.service - batch.arrival,
-                          wait=starts - batch.arrival,
-                          p_helper=None, blocked=None, start=starts)
+    with engines.call_span("repro.assemble"):
+        starts = np.asarray(starts)
+        return BatchSimResult(response=starts + batch.service - batch.arrival,
+                              wait=starts - batch.arrival,
+                              p_helper=None, blocked=None, start=starts)
 
 
 def _modbs_result(batch: BatchTrace, blocked, starts) -> BatchSimResult:
-    blocked = np.asarray(blocked)
-    starts = np.asarray(starts)
-    return BatchSimResult(response=starts + batch.service - batch.arrival,
-                          wait=starts - batch.arrival,
-                          p_helper=blocked.mean(axis=1), blocked=blocked,
-                          p_routed=blocked.mean(axis=1), start=starts)
+    with engines.call_span("repro.assemble"):
+        blocked = np.asarray(blocked)
+        starts = np.asarray(starts)
+        return BatchSimResult(response=starts + batch.service - batch.arrival,
+                              wait=starts - batch.arrival,
+                              p_helper=blocked.mean(axis=1), blocked=blocked,
+                              p_routed=blocked.mean(axis=1), start=starts)
 
 
 def _bs_check_ovf(ovf, q_cap: int, cell: str = "") -> None:
@@ -320,12 +358,13 @@ def _bs_assemble(batch: BatchTrace, starts, served,
 
 def _bs_result(batch: BatchTrace, tagged, rec_t, ovf,
                q_cap: int) -> BatchSimResult:
-    _bs_check_ovf(ovf, q_cap)
-    # one vectorized event->job scatter for the whole batch (no per-rep
-    # Python loop: host post-processing must not scale with R)
-    starts, served, routed = _bs_scatter_events(batch.num_jobs, tagged,
-                                                rec_t)
-    return _bs_assemble(batch, starts, served, routed)
+    with engines.call_span("repro.assemble"):
+        _bs_check_ovf(ovf, q_cap)
+        # one vectorized event->job scatter for the whole batch (no per-rep
+        # Python loop: host post-processing must not scale with R)
+        starts, served, routed = _bs_scatter_events(batch.num_jobs, tagged,
+                                                    rec_t)
+        return _bs_assemble(batch, starts, served, routed)
 
 
 # -- engine="jax" cores (the vmapped lax.scan substrate) --------------------
@@ -333,8 +372,9 @@ def _bs_result(batch: BatchTrace, tagged, rec_t, ovf,
 
 def _with_drain_obs(res: BatchSimResult, batch: BatchTrace,
                     fb) -> BatchSimResult:
-    return dataclasses.replace(
-        res, **flr.drain_observables(fb, batch, res.response))
+    with engines.call_span("repro.assemble"):
+        return dataclasses.replace(
+            res, **flr.drain_observables(fb, batch, res.response))
 
 
 def _merged_fcfs_inputs(batch: BatchTrace, fb) -> flr.MergedStream:
@@ -347,18 +387,18 @@ def _fcfs_jax(batch: BatchTrace, *, partition=None, wl=None, failures=None):
     """Batched multiserver-job FCFS over all replications at once."""
     if failures is None:
         with enable_x64():
-            starts = _call(_fcfs_scan_batch, *_fcfs_inputs(batch), batch.k)
+            starts = _fetch(_call(_fcfs_scan_batch, *_fcfs_inputs(batch),
+                                  batch.k))
         return _fcfs_result(batch, starts)
     flr.require_drain(failures, "jax")
     ms = _merged_fcfs_inputs(batch, failures)
     with enable_x64():
-        starts_m = _call(_fcfs_fail_scan_batch,
-                         _dev(ms.t, jnp.float64),
-                         _dev(ms.need, jnp.int32),
-                         _dev(ms.service, jnp.float64),
-                         _dev(ms.t_up, jnp.float64),
-                         _dev(ms.is_fail != 0, jnp.bool_), batch.k)
-    starts = np.take_along_axis(np.asarray(starts_m), ms.job_pos, axis=1)
+        starts_m = _fetch(_call(
+            _fcfs_fail_scan_batch,
+            *_puts((ms.t, jnp.float64), (ms.need, jnp.int32),
+                   (ms.service, jnp.float64), (ms.t_up, jnp.float64),
+                   (ms.is_fail != 0, jnp.bool_)), batch.k))
+    starts = np.take_along_axis(starts_m, ms.job_pos, axis=1)
     return _with_drain_obs(_fcfs_result(batch, starts), batch, failures)
 
 
@@ -368,8 +408,9 @@ def _modbs_jax(batch: BatchTrace, *, partition=None, wl=None, failures=None):
     slots, s_max, h = _partition_args(batch, partition, wl)
     if failures is None:
         with enable_x64():
-            blocked, starts = _call(_modbs_scan_batch, *_class_inputs(batch),
-                                    jnp.asarray(slots), s_max, h)
+            blocked, starts = _fetch(_call(
+                _modbs_scan_batch, *_class_inputs(batch), jnp.asarray(slots),
+                s_max, h))
         return _modbs_result(batch, blocked, starts)
     flr.require_drain(failures, "jax")
     part = partition if partition is not None else balanced_partition(wl)
@@ -377,15 +418,14 @@ def _modbs_jax(batch: BatchTrace, *, partition=None, wl=None, failures=None):
     ms = flr.merge_failure_stream(batch, ft, ftgt, fup, count,
                                   pad_cls=len(part.a))
     with enable_x64():
-        blocked_m, starts_m = _call(
+        blocked_m, starts_m = _fetch(_call(
             _modbs_fail_scan_batch,
-            _dev(ms.t, jnp.float64), _dev(ms.cls, jnp.int32),
-            _dev(ms.need, jnp.int32),
-            _dev(ms.service, jnp.float64),
-            _dev(ms.t_up, jnp.float64),
-            _dev(ms.is_fail != 0, jnp.bool_), jnp.asarray(slots), s_max, h)
-    starts = np.take_along_axis(np.asarray(starts_m), ms.job_pos, axis=1)
-    blocked = np.take_along_axis(np.asarray(blocked_m), ms.job_pos, axis=1)
+            *_puts((ms.t, jnp.float64), (ms.cls, jnp.int32),
+                   (ms.need, jnp.int32), (ms.service, jnp.float64),
+                   (ms.t_up, jnp.float64), (ms.is_fail != 0, jnp.bool_)),
+            jnp.asarray(slots), s_max, h))
+    starts = np.take_along_axis(starts_m, ms.job_pos, axis=1)
+    blocked = np.take_along_axis(blocked_m, ms.job_pos, axis=1)
     return _with_drain_obs(_modbs_result(batch, blocked, starts), batch,
                            failures)
 
@@ -425,17 +465,18 @@ def _bs_jax(batch: BatchTrace, *, partition=None, wl=None, queue_cap=None,
     slots, s_max, h, q_cap = _bs_args(batch, partition, wl, queue_cap)
     if failures is None:
         with enable_x64():
-            tagged, rec_t, ovf = _call(_bs_scan_batch, *_class_inputs(batch),
-                                       jnp.asarray(slots), s_max, h, q_cap)
+            tagged, rec_t, ovf = _fetch(_call(
+                _bs_scan_batch, *_class_inputs(batch), jnp.asarray(slots),
+                s_max, h, q_cap))
         return _bs_result(batch, tagged, rec_t, ovf, q_cap)
     flr.require_drain(failures, "jax")
     ft, ftgt, fup, length = _bs_fail_args(batch, failures, partition, wl)
     with enable_x64():
-        tagged, rec_t, ovf = _call(
+        tagged, rec_t, ovf = _fetch(_call(
             _bs_fail_scan_batch, *_class_inputs(batch),
-            _dev(ft, jnp.float64), _dev(ftgt, jnp.int32),
-            _dev(fup, jnp.float64), jnp.asarray(slots), s_max, h,
-            q_cap, length)
+            *_puts((ft, jnp.float64), (ftgt, jnp.int32),
+                   (fup, jnp.float64)), jnp.asarray(slots), s_max, h,
+            q_cap, length))
     return _with_drain_obs(_bs_result(batch, tagged, rec_t, ovf, q_cap),
                            batch, failures)
 
@@ -494,15 +535,22 @@ def _srpt_no_failures(failures, policy: str) -> None:
 def _srpt_result(batch: BatchTrace, job_ev, t_ev, fs_ev, ovf, npre, ne,
                  q_cap: int, peak=None) -> BatchSimResult:
     """Event streams -> BatchSimResult, the `_python_core` op order
-    (response = completion - arrival, wait = first start - arrival)."""
-    _srpt_check_ovf(ovf, q_cap, peak=peak)
-    assert (np.asarray(ne) == 2 * batch.num_jobs).all(), \
-        "SRPT event scan under-ran its 2J event budget"
-    comp, fstart = _srpt_scatter_events(batch.num_jobs, job_ev, t_ev, fs_ev)
-    return BatchSimResult(response=comp - batch.arrival,
-                          wait=fstart - batch.arrival,
-                          p_helper=None, blocked=None, start=fstart,
-                          preemptions=np.asarray(npre).astype(np.int64))
+    (response = completion - arrival, wait = first start - arrival).
+    The scan's in-system ``peak`` and ``q_cap`` raise the ``srpt_peak``
+    and ``srpt_q`` counters."""
+    with engines.call_span("repro.assemble"):
+        _srpt_check_ovf(ovf, q_cap, peak=peak)
+        assert (np.asarray(ne) == 2 * batch.num_jobs).all(), \
+            "SRPT event scan under-ran its 2J event budget"
+        if peak is not None:
+            spans.high("srpt_peak", np.max(peak))
+            spans.high("srpt_q", q_cap)
+        comp, fstart = _srpt_scatter_events(batch.num_jobs, job_ev, t_ev,
+                                            fs_ev)
+        return BatchSimResult(response=comp - batch.arrival,
+                              wait=fstart - batch.arrival,
+                              p_helper=None, blocked=None, start=fstart,
+                              preemptions=np.asarray(npre).astype(np.int64))
 
 
 def _srpt_jax(sf: bool, batch: BatchTrace, *, partition=None, wl=None,
@@ -512,13 +560,10 @@ def _srpt_jax(sf: bool, batch: BatchTrace, *, partition=None, wl=None,
     q_cap = _srpt_args(batch, queue_cap)
     NU = _srpt_nu(batch)
     with enable_x64():
-        job_ev, t_ev, fs_ev, ovf, npre, ne, peak = _call(
+        job_ev, t_ev, fs_ev, ovf, npre, ne, peak = _fetch(_call(
             partial(_srpt_scan_batch, Q=q_cap, NU=NU, sf=sf,
                     k_mult=_srpt_k_mult(NU, batch)),
-            _dev(batch.arrival, jnp.float64),
-            _dev(batch.need, jnp.float64),
-            _dev(batch.service, jnp.float64),
-            _dev(np.full(batch.reps, float(batch.k)), jnp.float64))
+            *_srpt_inputs(batch)))
     return _srpt_result(batch, job_ev, t_ev, fs_ev, ovf, npre, ne, q_cap,
                         peak=peak)
 

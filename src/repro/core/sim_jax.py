@@ -1072,14 +1072,15 @@ def _bs_scatter_events(J: int, tagged, rec_t):
 
 def _bs_args(trace_or_batch, partition, wl, queue_cap):
     """Shared argument validation for ``bs_sim`` / ``bs_sim_batch``."""
-    if partition is None:
-        if wl is None:
-            raise ValueError("need a partition or a workload")
-        partition = balanced_partition(wl)
-    slots = np.asarray(partition.slots, dtype=np.int32)
-    h = int(partition.helpers)
-    if h < int(trace_or_batch.need.max()):
-        raise ValueError("helper set smaller than the largest server need")
+    with engines.call_span("repro.prep"):
+        if partition is None:
+            if wl is None:
+                raise ValueError("need a partition or a workload")
+            partition = balanced_partition(wl)
+        slots = np.asarray(partition.slots, dtype=np.int32)
+        h = int(partition.helpers)
+        if h < int(trace_or_batch.need.max()):
+            raise ValueError("helper set smaller than the largest server need")
     s_max = max(1, int(slots.max()))
     if queue_cap is None:
         queue_cap = max(1, min(trace_or_batch.num_jobs, 8192))

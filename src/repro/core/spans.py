@@ -1,0 +1,56 @@
+"""Host spans and counters of a simulator call.
+
+A span names a stretch of host time on the profiler's clock: it is a
+``jax.profiler.TraceAnnotation``, which records only while a profiler
+session is open (``jax.profiler.trace(dir)``) and then lands in the same
+trace as the device lanes, keyword metadata attached to the event.  With
+no session open a span costs about a microsecond.  Spans belong in host
+Python only: inside ``jit``, ``scan`` or ``shard_map`` one would fire
+once, at trace time.
+
+Counters stay in this process's memory: :func:`add` keeps a sum,
+:func:`high` a high-water mark, :func:`counters` returns a copy and
+:func:`reset` clears them.  Nothing is exported or written to a file.
+
+The batch path (``engines.simulate`` and the ``sim_batch`` helpers)
+opens ``repro.simulate`` around each call and, inside it, ``repro.prep``
+(input checks, partition, padding, copies and puts, until the inputs are
+on the device), ``repro.run`` (dispatch until the program is done),
+``repro.fetch`` (outputs to the host) and ``repro.assemble`` (overflow
+checks, event-to-job scatters, the result), all with the call's
+``call=<n>``.  Its counters: ``fetch_bytes`` (sum of the bytes fetched),
+``srpt_peak`` (the largest in-system job count an SRPT scan saw) and
+``srpt_q`` (the largest slot-table size Q it ran with).
+"""
+
+from __future__ import annotations
+
+import jax
+
+_COUNTERS: dict[str, int] = {}
+
+
+def span(name: str, **meta):
+    """A host span ``name`` carrying ``meta`` (a context manager)."""
+    return jax.profiler.TraceAnnotation(name, **meta)
+
+
+def add(name: str, n: int) -> None:
+    """Add ``n`` to the sum counter ``name``."""
+    _COUNTERS[name] = _COUNTERS.get(name, 0) + int(n)
+
+
+def high(name: str, v: int) -> None:
+    """Raise the high-water counter ``name`` to ``v`` if it is higher."""
+    v = int(v)
+    _COUNTERS[name] = max(_COUNTERS.get(name, v), v)
+
+
+def counters() -> dict[str, int]:
+    """A copy of every counter."""
+    return dict(_COUNTERS)
+
+
+def reset() -> None:
+    """Clear every counter."""
+    _COUNTERS.clear()

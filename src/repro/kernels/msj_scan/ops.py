@@ -28,11 +28,11 @@ from repro.core import engines
 from repro.core import failures as flr
 from repro.core.partition import balanced_partition
 from repro.core.sim_batch import (_bs_fail_args, _bs_result, _call,
-                                  _class_inputs, _dev, _fcfs_inputs,
-                                  _fcfs_result, _merged_fcfs_inputs,
-                                  _modbs_result, _partition_args,
-                                  _srpt_no_failures, _srpt_nu, _srpt_result,
-                                  _with_drain_obs)
+                                  _class_inputs, _fcfs_inputs, _fcfs_result,
+                                  _fetch, _merged_fcfs_inputs,
+                                  _modbs_result, _partition_args, _puts,
+                                  _srpt_inputs, _srpt_no_failures, _srpt_nu,
+                                  _srpt_result, _with_drain_obs)
 from repro.core.sim_jax import _bs_args, _srpt_args
 
 from .kernel import (bs_fail_scan_fwd, bs_scan_fwd, fcfs_fail_scan_fwd,
@@ -94,19 +94,19 @@ def _fcfs_pallas(batch, *, partition=None, wl=None, failures=None):
     if failures is None:
         with enable_x64():
             a, n, v = _fcfs_inputs(batch)
-            starts = _call(lambda a, n, v: fcfs_scan(a, n, v, k=batch.k),
-                           a, n, v)
+            starts = _fetch(_call(
+                lambda a, n, v: fcfs_scan(a, n, v, k=batch.k), a, n, v))
         return _fcfs_result(batch, starts)
     flr.require_drain(failures, "pallas")
     ms = _merged_fcfs_inputs(batch, failures)
     with enable_x64():
-        starts_m = _call(
+        starts_m = _fetch(_call(
             lambda t, n, v, tu, isf: fcfs_fail_scan_fwd(
                 t, n, v, tu, isf, k=batch.k, interpret=_interpret()),
-            _dev(ms.t, jnp.float64), _dev(ms.need, jnp.int32),
-            _dev(ms.service, jnp.float64), _dev(ms.t_up, jnp.float64),
-            _dev(ms.is_fail != 0, jnp.bool_))
-    starts = np.take_along_axis(np.asarray(starts_m), ms.job_pos, axis=1)
+            *_puts((ms.t, jnp.float64), (ms.need, jnp.int32),
+                   (ms.service, jnp.float64), (ms.t_up, jnp.float64),
+                   (ms.is_fail != 0, jnp.bool_))))
+    starts = np.take_along_axis(starts_m, ms.job_pos, axis=1)
     return _with_drain_obs(_fcfs_result(batch, starts), batch, failures)
 
 
@@ -116,10 +116,10 @@ def _modbs_pallas(batch, *, partition=None, wl=None, failures=None):
     slots, s_max, h = _partition_args(batch, partition, wl)
     if failures is None:
         with enable_x64():
-            blocked, starts = _call(
+            blocked, starts = _fetch(_call(
                 lambda a, c, n, v: modbs_scan(a, c, n, v, slots=slots,
                                               s_max=s_max, h=h),
-                *_class_inputs(batch))
+                *_class_inputs(batch)))
         return _modbs_result(batch, blocked, starts)
     flr.require_drain(failures, "pallas")
     part = partition if partition is not None else balanced_partition(wl)
@@ -127,15 +127,15 @@ def _modbs_pallas(batch, *, partition=None, wl=None, failures=None):
     ms = flr.merge_failure_stream(batch, ft, ftgt, fup, count,
                                   pad_cls=len(part.a))
     with enable_x64():
-        blocked_m, starts_m = _call(
+        blocked_m, starts_m = _fetch(_call(
             lambda t, c, n, v, tu, isf: modbs_fail_scan_fwd(
                 t, c, n, v, tu, isf, jnp.asarray(slots, jnp.int32),
                 s_max=s_max, h=h, interpret=_interpret()),
-            _dev(ms.t, jnp.float64), _dev(ms.cls, jnp.int32),
-            _dev(ms.need, jnp.int32), _dev(ms.service, jnp.float64),
-            _dev(ms.t_up, jnp.float64), _dev(ms.is_fail != 0, jnp.bool_))
-    starts = np.take_along_axis(np.asarray(starts_m), ms.job_pos, axis=1)
-    blocked = np.take_along_axis(np.asarray(blocked_m), ms.job_pos, axis=1)
+            *_puts((ms.t, jnp.float64), (ms.cls, jnp.int32),
+                   (ms.need, jnp.int32), (ms.service, jnp.float64),
+                   (ms.t_up, jnp.float64), (ms.is_fail != 0, jnp.bool_))))
+    starts = np.take_along_axis(starts_m, ms.job_pos, axis=1)
+    blocked = np.take_along_axis(blocked_m, ms.job_pos, axis=1)
     return _with_drain_obs(_modbs_result(batch, blocked, starts), batch,
                            failures)
 
@@ -147,22 +147,22 @@ def _bs_pallas(batch, *, partition=None, wl=None, queue_cap=None,
     slots, s_max, h, q_cap = _bs_args(batch, partition, wl, queue_cap)
     if failures is None:
         with enable_x64():
-            tagged, rec_t, ovf = _call(
+            tagged, rec_t, ovf = _fetch(_call(
                 lambda a, c, n, v: bs_scan(a, c, n, v, slots=slots,
                                            s_max=s_max, h=h, q_cap=q_cap),
-                *_class_inputs(batch))
+                *_class_inputs(batch)))
         return _bs_result(batch, tagged, rec_t, ovf, q_cap)
     flr.require_drain(failures, "pallas")
     ft, ftgt, fup, length = _bs_fail_args(batch, failures, partition, wl)
     with enable_x64():
-        tagged, rec_t, ovf = _call(
+        tagged, rec_t, ovf = _fetch(_call(
             lambda a, c, n, v, t1, t2, t3: bs_fail_scan_fwd(
                 a, c, n, v, t1, t2, t3, jnp.asarray(slots, jnp.int32),
                 s_max=s_max, h=h, q_cap=q_cap, length=length,
                 interpret=_interpret()),
             *_class_inputs(batch),
-            _dev(ft, jnp.float64), _dev(ftgt, jnp.int32),
-            _dev(fup, jnp.float64))
+            *_puts((ft, jnp.float64), (ftgt, jnp.int32),
+                   (fup, jnp.float64))))
     return _with_drain_obs(_bs_result(batch, tagged, rec_t, ovf, q_cap),
                            batch, failures)
 
@@ -174,12 +174,9 @@ def _srpt_pallas(sf: bool, batch, *, partition=None, wl=None,
     q_cap = _srpt_args(batch, queue_cap)
     NU = _srpt_nu(batch)
     with enable_x64():
-        job_ev, t_ev, fs_ev, ovf, npre, ne, peak = _call(
+        job_ev, t_ev, fs_ev, ovf, npre, ne, peak = _fetch(_call(
             lambda a, n, v, k: srpt_scan(a, n, v, k, Q=q_cap, NU=NU, sf=sf),
-            _dev(batch.arrival, jnp.float64),
-            _dev(batch.need, jnp.float64),
-            _dev(batch.service, jnp.float64),
-            _dev(np.full(batch.reps, float(batch.k)), jnp.float64))
+            *_srpt_inputs(batch)))
     return _srpt_result(batch, job_ev, t_ev, fs_ev, ovf, npre, ne, q_cap,
                         peak=peak)
 

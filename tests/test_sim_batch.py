@@ -1,10 +1,4 @@
-"""Batched substrate: sampling determinism, sweep API, bench harness."""
-
-import json
-import os
-import subprocess
-import sys
-import time
+"""Batched substrate: sampling determinism, sweep API, runtime pinning."""
 
 import numpy as np
 import pytest
@@ -142,253 +136,3 @@ def test_backends_initialized_probe_agrees_with_reality():
     # after init the probe must say so (a probe that lost its API would
     # raise here instead of silently disabling the pin)
     assert _backends_initialized() is True
-
-
-# -- bench regression guard ---------------------------------------------------
-
-
-def _fake_report(jps_by_key):
-    # 2-tuple keys default to the fig1 scenario; 3-tuples name one
-    rows = []
-    for key, v in jps_by_key.items():
-        bench, (e, p) = ("fig1-critical", key) if len(key) == 2 \
-            else (key[0], key[1:])
-        rows.append({"bench": bench, "engine": e, "policy": p,
-                     "jobs_per_sec": v})
-    return {"schema": "bench_sim/v1", "config": {}, "rows": rows}
-
-
-def test_check_bench_regression_device_count_cells():
-    mod = pytest.importorskip(
-        "benchmarks.check_bench_regression",
-        reason="benchmarks package needs repo root on sys.path")
-
-    def report(rows):
-        return {"schema": "bench_sim/v1", "config": {}, "rows": rows}
-
-    def row(engine, policy, jps, dc=None):
-        r = {"bench": "fig1-critical", "engine": engine, "policy": policy,
-             "jobs_per_sec": jps}
-        if dc is not None:
-            r["device_count"] = dc
-        return r
-
-    base = report([row("jax-shard", "fcfs", 4000.0, dc=4),
-                   row("jax-shard", "fcfs", 1000.0, dc=1),
-                   row("python", "fcfs", 100.0)])
-    # same topology compares: a collapse of the dc=4 cell trips on a
-    # >=4-cpu host ...
-    slow4 = report([row("jax-shard", "fcfs", 900.0, dc=4),
-                    row("python", "fcfs", 100.0)])
-    failures = mod.check(slow4, base, factor=2.0, host_cpus=8)
-    assert len(failures) == 1 and "[devices=4]" in failures[0]
-    # ... but is skipped — not failed — when the committed topology
-    # over-subscribes this host's cores
-    assert mod.check(slow4, base, factor=2.0, host_cpus=2) == []
-    # different topologies never compare: a slow dc=2 cell has no dc=2
-    # baseline, and the dc=1 baseline must not be used against it
-    slow2 = report([row("jax-shard", "fcfs", 10.0, dc=2),
-                    row("python", "fcfs", 100.0)])
-    assert mod.check(slow2, base, factor=2.0, host_cpus=8) == []
-    # the dc=1 cell is still guarded independently
-    slow1 = report([row("jax-shard", "fcfs", 400.0, dc=1),
-                    row("python", "fcfs", 100.0)])
-    failures = mod.check(slow1, base, factor=2.0, host_cpus=8)
-    assert len(failures) == 1 and "[devices=" not in failures[0]
-    # python rows are topology-pinned to dc=1: a python row measured in a
-    # forced-4-device process still feeds the machine-speed ratio
-    slow_host = report([row("jax-shard", "fcfs", 1800.0, dc=4),
-                        row("python", "fcfs", 50.0, dc=4)])
-    assert mod.check(slow_host, base, factor=2.0, host_cpus=8) == []
-
-
-def test_check_bench_regression_passes_and_fails_correctly():
-    mod = pytest.importorskip(
-        "benchmarks.check_bench_regression",
-        reason="benchmarks package needs repo root on sys.path")
-    check = mod.check
-
-    base = _fake_report({("jax-batch", "fcfs"): 1000.0,
-                         ("python", "fcfs"): 100.0})
-    same = _fake_report({("jax-batch", "fcfs"): 990.0,
-                         ("python", "fcfs"): 95.0})
-    assert check(same, base, factor=2.0) == []
-    # >2x slowdown on one pair -> exactly that pair flagged
-    slow = _fake_report({("jax-batch", "fcfs"): 400.0,
-                         ("python", "fcfs"): 95.0})
-    failures = check(slow, base, factor=2.0)
-    assert len(failures) == 1 and "jax-batch/fcfs" in failures[0]
-    # unseen (engine, policy) pairs are not compared
-    new_engine = _fake_report({("pallas", "fcfs"): 1.0})
-    assert check(new_engine, base, factor=2.0) == []
-    # a uniformly 2.5x-slower CI host is NOT a regression: the python-row
-    # ratio normalizes the floor (hardware speed is not a code change)
-    slow_host = _fake_report({("jax-batch", "fcfs"): 400.0,
-                              ("python", "fcfs"): 40.0})
-    assert check(slow_host, base, factor=2.0) == []
-    # ...but a jitted-engine collapse on that same slow host still trips
-    slow_host_regressed = _fake_report({("jax-batch", "fcfs"): 70.0,
-                                        ("python", "fcfs"): 40.0})
-    failures = check(slow_host_regressed, base, factor=2.0)
-    assert len(failures) == 1 and "jax-batch/fcfs" in failures[0]
-    # a faster host never loosens the bar (ratio capped at 1)
-    fast_host = _fake_report({("jax-batch", "fcfs"): 450.0,
-                              ("python", "fcfs"): 300.0})
-    assert len(check(fast_host, base, factor=2.0)) == 1
-    # scenarios are guarded independently: a collapse in the traces
-    # scenario trips even when the fig1 cell of the same pair is healthy
-    base2 = _fake_report({("jax-batch", "fcfs"): 1000.0,
-                          ("python", "fcfs"): 100.0,
-                          ("traces", "jax-batch", "fcfs"): 800.0})
-    tr_slow = _fake_report({("jax-batch", "fcfs"): 990.0,
-                            ("python", "fcfs"): 100.0,
-                            ("traces", "jax-batch", "fcfs"): 100.0})
-    failures = check(tr_slow, base2, factor=2.0)
-    assert len(failures) == 1 and "traces:jax-batch/fcfs" in failures[0]
-
-
-def test_check_bench_regression_missing_committed_cells():
-    mod = pytest.importorskip(
-        "benchmarks.check_bench_regression",
-        reason="benchmarks package needs repo root on sys.path")
-
-    def report(rows, config=None):
-        return {"schema": "bench_sim/v1", "config": config or {},
-                "rows": rows}
-
-    def row(bench, engine, policy, jps, dc=1):
-        return {"bench": bench, "engine": engine, "policy": policy,
-                "jobs_per_sec": jps, "device_count": dc}
-
-    base = report([row("fig1-critical", "jax-batch", "fcfs", 1000.0),
-                   row("fig1-critical", "jax-batch", "bs-fcfs", 800.0),
-                   row("grid", "jax-batch", "fcfs", 2000.0),
-                   row("fig1-critical", "jax-shard", "fcfs", 900.0, dc=4),
-                   row("fig1-critical", "python", "fcfs", 100.0)])
-    cfg_all = {"scenario": "all", "device_count": 1,
-               "engines": ["python", "jax-batch", "jax-shard"]}
-    # a full-coverage run that silently drops a committed cell fails
-    # loudly (the dc=4 jax-shard cell is NOT required: this run's
-    # topology is dc=1, so it could not have produced that cell)
-    fresh = report([row("fig1-critical", "jax-batch", "fcfs", 1000.0),
-                    row("grid", "jax-batch", "fcfs", 2000.0),
-                    row("fig1-critical", "python", "fcfs", 100.0)],
-                   cfg_all)
-    failures = mod.check(fresh, base, factor=2.0, host_cpus=8)
-    assert len(failures) == 1
-    assert "missing" in failures[0] and "bs-fcfs" in failures[0]
-    # scenario scoping: a fig1-only run owes no grid rows
-    cfg_fig1 = dict(cfg_all, scenario="fig1")
-    fresh_fig1 = report(
-        [row("fig1-critical", "jax-batch", "fcfs", 1000.0),
-         row("fig1-critical", "jax-batch", "bs-fcfs", 800.0),
-         row("fig1-critical", "python", "fcfs", 100.0)], cfg_fig1)
-    assert mod.check(fresh_fig1, base, factor=2.0, host_cpus=8) == []
-    # engine scoping: a --engines jax-batch run owes no python rows
-    cfg_nopy = dict(cfg_fig1, engines=["jax-batch"])
-    fresh_nopy = report(
-        [row("fig1-critical", "jax-batch", "fcfs", 1000.0),
-         row("fig1-critical", "jax-batch", "bs-fcfs", 800.0)], cfg_nopy)
-    assert mod.check(fresh_nopy, base, factor=2.0, host_cpus=8) == []
-    # topology scoping: a dc=4 jax-shard run that drops its committed
-    # dc=4 cell fails — unless that topology over-subscribes the host
-    cfg_dc4 = dict(cfg_fig1, device_count=4, engines=["jax-shard"])
-    failures = mod.check(report([], cfg_dc4), base, factor=2.0,
-                         host_cpus=8)
-    assert len(failures) == 1 and "jax-shard" in failures[0]
-    assert mod.check(report([], cfg_dc4), base, factor=2.0,
-                     host_cpus=2) == []
-    # pre-config reports (no scenario recorded) skip the guard entirely
-    assert mod.check(report([]), base, factor=2.0, host_cpus=8) == []
-
-
-# -- bench harness ------------------------------------------------------------
-
-
-@pytest.mark.slow
-def test_bench_sim_smoke_emits_well_formed_json(tmp_path):
-    bench_sim = pytest.importorskip(
-        "benchmarks.bench_sim",
-        reason="benchmarks package needs repo root on sys.path")
-    out = tmp_path / "BENCH_sim.json"
-    # subprocess, not in-process: pin_single_thread_runtime() must run
-    # before the first JAX computation to take effect, and pytest has
-    # already initialized the backend by now
-    repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.pathsep.join(
-        [os.path.join(repo_root, "src"), env.get("PYTHONPATH", "")])
-    # the smoke budget assumes the default topology: an inherited forced
-    # device count (e.g. from the CI shard job) must not leak in
-    env.pop("XLA_FLAGS", None)
-    t0 = time.time()
-    subprocess.run(
-        [sys.executable, "-m", "benchmarks.bench_sim", "--smoke",
-         "--out", str(out)],
-        check=True, cwd=repo_root, env=env, capture_output=True)
-    wall = time.time() - t0
-    # the budget grew 60 -> 75 s with the sixth (srpt) scenario, then
-    # 75 -> 110 s when pallas gained drain-mode failure kernels and the
-    # srpt bitonic kernels (three failure cells plus two interpret-mode
-    # bitonic srpt cells, each timed twice for the cold/warm split)
-    assert wall < 110, f"--smoke took {wall:.1f}s, budget is 110s"
-    on_disk = json.loads(out.read_text())
-    assert on_disk["schema"] == bench_sim.SCHEMA
-    rows = on_disk["rows"]
-    # fig1: 5 engines x 3 policies per k; traces: 4 engines x 3 policies;
-    # failures: 4 engines x 3 policies (pallas runs the drain-mode fail
-    # kernels); grid: 2 engines x 3 policies (jax-batch + jax-shard — no
-    # python baseline, no pallas grid core); streaming: jax-batch x 3
-    # policies; srpt: python x 2 policies + (jax-batch + pallas +
-    # jax-shard) x 2 policies (batch cells only — smoke skips the srpt
-    # grid part, whose rows would land in the same regression-guard
-    # cells anyway)
-    assert len(rows) == \
-        15 * len(on_disk["config"]["ks"]) + 12 + 12 + 6 + 3 + 8
-    assert {r["bench"] for r in rows} == {"fig1-critical", "traces",
-                                          "failures", "grid", "streaming",
-                                          "srpt"}
-    for r in rows:
-        assert set(bench_sim.ROW_KEYS) <= set(r)
-        assert r["engine"] in bench_sim.ALL_ENGINES
-        assert r["jobs_per_sec"] > 0 and r["wall_s"] > 0
-        assert r["device_count"] >= 1
-        if r["engine"] == "python" or r["bench"] in ("grid", "streaming"):
-            assert r["speedup_vs_python"] is None
-        elif r["bench"] == "srpt":
-            # only the python_k batch cells price a baseline (full-scale
-            # runs add grid-native srpt rows without one)
-            assert (r["speedup_vs_python"] is None
-                    or r["speedup_vs_python"] > 0)
-        else:
-            assert r["speedup_vs_python"] > 0
-    streaming = [r for r in rows if r["bench"] == "streaming"]
-    assert {r["policy"] for r in streaming} == {"fcfs", "modbs-fcfs",
-                                                "bs-fcfs"}
-    for r in streaming:
-        assert r["chunk_jobs"] >= 1     # streaming-only extra key
-        assert r["peak_rss_mb"] > 0
-    grid = [r for r in rows if r["bench"] == "grid"]
-    assert {r["policy"] for r in grid} == {"fcfs", "modbs-fcfs", "bs-fcfs"}
-    for r in grid:
-        assert r["percell_jobs_per_sec"] > 0   # grid-only extra keys
-        assert r["grid_speedup"] > 0
-    # the one-program-per-figure claim, asserted: the whole k-grid
-    # compiles exactly one XLA program per policy on the in-process path
-    assert all(r["compile_count"] == 1 for r in grid
-               if r["engine"] == "jax-batch")
-    srpt = [r for r in rows if r["bench"] == "srpt"]
-    assert {r["policy"] for r in srpt} == {"ff-srpt", "sf-srpt"}
-    # every jitted srpt row is exactly one compiled XLA program
-    assert all(r["compile_count"] == 1 for r in srpt
-               if r["engine"] != "python")
-    # the point of the substrate: batched beats the event engine — in the
-    # synthetic scenario, on the empirical bootstrap batch, and with the
-    # failure branch live in every scan step.  The srpt bench is excluded
-    # here: its scan-vs-oracle win needs the full-scale replication count
-    # (the committed rows), not the smoke config
-    batched = [r for r in rows if r["engine"] == "jax-batch"
-               and r["bench"] not in ("grid", "streaming", "srpt")]
-    assert {r["bench"] for r in batched} == {"fig1-critical", "traces",
-                                             "failures"}
-    assert all(r["speedup_vs_python"] > 1 for r in batched)
