@@ -89,7 +89,8 @@ from .sim_jax import (_bs_args, _bs_core, _bs_fail_core,
                       _fcfs_fail_core, _fcfs_fail_stream_core,
                       _fcfs_stream_core, _modbs_core, _modbs_fail_core,
                       _modbs_fail_stream_core, _modbs_stream_core,
-                      _srpt_args, _srpt_core, _srpt_stream_core)
+                      _srpt_args, _srpt_core, _srpt_pairwise,
+                      _srpt_stream_core)
 from .workload import BatchTrace
 
 _FLAG = "--xla_force_host_platform_device_count"
@@ -392,12 +393,13 @@ def _bs_shard_call(arrival, cls, need, service, slots, s_max: int, h: int,
         arrival, cls, need, service, slots)
 
 
-@partial(jax.jit, static_argnums=(4, 5, 6, 7, 8))
+@partial(jax.jit, static_argnums=(4, 5, 6, 7, 8, 9))
 def _srpt_shard_call(arrival, need, service, kk, Q: int, NU: tuple,
-                     sf: bool, k_mult: bool, mesh: Mesh):
+                     sf: bool, k_mult: bool, pairwise: bool, mesh: Mesh):
     # _srpt_core carries the lane axis natively (per-lane sorts and
     # 1-entry scatters, no cross-lane ops) — each shard runs its slice.
-    body = lambda a, n, v, k: _srpt_core(a, n, v, k, Q, NU, sf, k_mult)
+    body = lambda a, n, v, k: _srpt_core(a, n, v, k, Q, NU, sf, k_mult,
+                                         pairwise)
     return shard_map(body, mesh=mesh, in_specs=(P("r"),) * 4,
                      out_specs=(P("r"),) * 7)(arrival, need, service, kk)
 
@@ -536,15 +538,15 @@ def _srpt_jax_shard(sf: bool, batch, *, partition=None, wl=None,
     policy = "sf-srpt" if sf else "ff-srpt"
     _srpt_no_failures(failures, policy)
     q_cap = _srpt_args(batch, queue_cap)
-    NU = _srpt_nu(batch)
+    NU, pairwise = _srpt_nu(batch), _srpt_pairwise(q_cap)
     mesh = local_mesh(devices)
     padded, R = _pad_batch(batch, mesh.size)
     with enable_x64():
         job_ev, t_ev, fs_ev, ovf, npre, ne, peak = _fetch(_call(
             _srpt_shard_call, *_srpt_inputs(padded),
-            q_cap, NU, sf, _srpt_k_mult(NU, batch), mesh), R)
+            q_cap, NU, sf, _srpt_k_mult(NU, batch), pairwise, mesh), R)
     return _srpt_result(batch, job_ev, t_ev, fs_ev, ovf, npre, ne, q_cap,
-                        peak=peak)
+                        peak=peak, pairwise=pairwise)
 
 
 @engines.register("sf-srpt", "jax-shard")
@@ -807,14 +809,14 @@ def _bs_grid_shard_call(carry, arrival, cls, need, service, j_live,
         carry, arrival, cls, need, service, j_live)
 
 
-@partial(jax.jit, static_argnums=(6, 7, 8, 9, 10, 11))
+@partial(jax.jit, static_argnums=(6, 7, 8, 9, 10, 11, 12))
 def _srpt_grid_shard_call(carry, arrival, need, service, kk, j_live,
                           Q: int, NU: tuple, sf: bool, length: int,
-                          k_mult: bool, mesh: Mesh):
+                          k_mult: bool, pairwise: bool, mesh: Mesh):
     def body(c, a, n, v, k, jl):
         f = lambda c1, a1, n1, v1, k1, jl1: _srpt_stream_core(
             a1, n1, v1, k1, c1, Q, NU, sf, length, j_live=jl1,
-            k_mult=k_mult)
+            k_mult=k_mult, pairwise=pairwise)
         return jax.vmap(f)(c, a, n, v, k, jl)
     return shard_map(body, mesh=mesh, in_specs=(P("c", "r"),) * 6,
                      out_specs=(P("c", "r"),) * 4)(
@@ -1001,7 +1003,8 @@ def _srpt_grid_shard(sf: bool, cells, devices=None):
             _dev(pg(p["service"]), jnp.float64),
             _dev(pg(p["kk"]), jnp.float64),
             _dev(pg(p["j_live"]), jnp.int32),
-            p["Q_pad"], p["NU"], sf, 2 * p["J_pad"], p["k_mult"], mesh)
+            p["Q_pad"], p["NU"], sf, 2 * p["J_pad"], p["k_mult"],
+            p["pairwise"], mesh)
     return _srpt_grid_extract(
         cells, p, np.asarray(job_ev)[:G, :R], np.asarray(t_ev)[:G, :R],
         np.asarray(fs_ev)[:G, :R], np.asarray(carry[2])[:G, :R],

@@ -59,8 +59,8 @@ from .sim_jax import (_BIG, _SRPT_COLS, _bs_args, _bs_core, _bs_fail_core,
                       _fcfs_fail_stream_core, _fcfs_stream_core, _loss_core,
                       _modbs_core, _modbs_fail_core,
                       _modbs_fail_stream_core, _modbs_stream_core,
-                      _srpt_args, _srpt_core, _srpt_scatter_events,
-                      _srpt_stream_core)
+                      _srpt_args, _srpt_core, _srpt_pairwise,
+                      _srpt_scatter_events, _srpt_stream_core)
 from .workload import BatchTrace, Workload
 
 #: waiting-time epsilon for P[wait > 0] — matches ``Simulation.wait_eps``
@@ -484,13 +484,14 @@ def _bs_jax(batch: BatchTrace, *, partition=None, wl=None, queue_cap=None,
 # -- preemptive SRPT-family cores (sf-srpt / ff-srpt) -----------------------
 
 
-@partial(jax.jit, static_argnames=("Q", "NU", "sf", "k_mult"),
+@partial(jax.jit, static_argnames=("Q", "NU", "sf", "k_mult", "pairwise"),
          donate_argnums=(0, 1, 2))
 def _srpt_scan_batch(arrival, need, service, kk, Q: int, NU: tuple,
-                     sf: bool, k_mult: bool):
+                     sf: bool, k_mult: bool, pairwise: bool):
     # _srpt_core carries the replications axis natively (per-lane sorts
     # and 1-entry scatters) — no vmap; see the sim_jax section comment.
-    return _srpt_core(arrival, need, service, kk, Q, NU, sf, k_mult)
+    return _srpt_core(arrival, need, service, kk, Q, NU, sf, k_mult,
+                      pairwise)
 
 
 def _srpt_nu(*batches) -> tuple:
@@ -533,11 +534,14 @@ def _srpt_no_failures(failures, policy: str) -> None:
 
 
 def _srpt_result(batch: BatchTrace, job_ev, t_ev, fs_ev, ovf, npre, ne,
-                 q_cap: int, peak=None) -> BatchSimResult:
+                 q_cap: int, peak=None,
+                 pairwise: bool = False) -> BatchSimResult:
     """Event streams -> BatchSimResult, the `_python_core` op order
     (response = completion - arrival, wait = first start - arrival).
     The scan's in-system ``peak`` and ``q_cap`` raise the ``srpt_peak``
-    and ``srpt_q`` counters."""
+    and ``srpt_q`` counters; a ``pairwise`` scan (the fast step ordered
+    its slot table by precedence counts) adds its 2J events per
+    replication to ``srpt_pairwise_events``."""
     with engines.call_span("repro.assemble"):
         _srpt_check_ovf(ovf, q_cap, peak=peak)
         assert (np.asarray(ne) == 2 * batch.num_jobs).all(), \
@@ -545,6 +549,9 @@ def _srpt_result(batch: BatchTrace, job_ev, t_ev, fs_ev, ovf, npre, ne,
         if peak is not None:
             spans.high("srpt_peak", np.max(peak))
             spans.high("srpt_q", q_cap)
+            if pairwise:
+                spans.add("srpt_pairwise_events",
+                          2 * batch.num_jobs * batch.reps)
         comp, fstart = _srpt_scatter_events(batch.num_jobs, job_ev, t_ev,
                                             fs_ev)
         return BatchSimResult(response=comp - batch.arrival,
@@ -558,14 +565,14 @@ def _srpt_jax(sf: bool, batch: BatchTrace, *, partition=None, wl=None,
     policy = "sf-srpt" if sf else "ff-srpt"
     _srpt_no_failures(failures, policy)
     q_cap = _srpt_args(batch, queue_cap)
-    NU = _srpt_nu(batch)
+    NU, pairwise = _srpt_nu(batch), _srpt_pairwise(q_cap)
     with enable_x64():
         job_ev, t_ev, fs_ev, ovf, npre, ne, peak = _fetch(_call(
             partial(_srpt_scan_batch, Q=q_cap, NU=NU, sf=sf,
-                    k_mult=_srpt_k_mult(NU, batch)),
+                    k_mult=_srpt_k_mult(NU, batch), pairwise=pairwise),
             *_srpt_inputs(batch)))
     return _srpt_result(batch, job_ev, t_ev, fs_ev, ovf, npre, ne, q_cap,
-                        peak=peak)
+                        peak=peak, pairwise=pairwise)
 
 
 @engines.register("sf-srpt", "jax")
@@ -684,13 +691,14 @@ def _bs_fail_grid_chunk(carry, arrival, cls, need, service, ft, ftgt, fup,
                                 j_live=j_live)
 
 
-@partial(jax.jit, static_argnums=(6, 7, 8, 9, 10),
+@partial(jax.jit, static_argnums=(6, 7, 8, 9, 10, 11),
          donate_argnums=(1, 2, 3))
 def _srpt_grid_chunk(carry, arrival, need, service, kk, j_live,
                      Q: int, NU: tuple, sf: bool, length: int,
-                     k_mult: bool):
+                     k_mult: bool, pairwise: bool):
     return _srpt_stream_core(arrival, need, service, kk, carry, Q, NU,
-                             sf, length, j_live=j_live, k_mult=k_mult)
+                             sf, length, j_live=j_live, k_mult=k_mult,
+                             pairwise=pairwise)
 
 
 # -- host-side grid plans: stacked [G, R, ...] inputs + per-lane carries ----
@@ -971,7 +979,8 @@ def _srpt_grid_plan(cells) -> dict:
                 kk=np.ascontiguousarray(kk),
                 j_live=np.ascontiguousarray(j_live),
                 NU=NU, k_mult=_srpt_k_mult(NU, *[c.batch for c in cells]),
-                Q_pad=max(q_caps), q_caps=q_caps, J_pad=J_pad)
+                Q_pad=max(q_caps), pairwise=_srpt_pairwise(max(q_caps)),
+                q_caps=q_caps, J_pad=J_pad)
 
 
 def _srpt_grid_carry(lead: tuple, Q: int):
@@ -1152,7 +1161,8 @@ def _srpt_grid(sf: bool, cells):
             _dev(p["service"].reshape(L, -1), jnp.float64),
             _dev(p["kk"].reshape(L), jnp.float64),
             _dev(p["j_live"].reshape(L), jnp.int32),
-            p["Q_pad"], p["NU"], sf, 2 * p["J_pad"], p["k_mult"])
+            p["Q_pad"], p["NU"], sf, 2 * p["J_pad"], p["k_mult"],
+            p["pairwise"])
     return _srpt_grid_extract(
         cells, p, np.asarray(job_ev).reshape(G, R, -1),
         np.asarray(t_ev).reshape(G, R, -1),

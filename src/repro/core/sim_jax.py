@@ -1390,7 +1390,7 @@ def _srpt_init(R: int, Q: int, dt):
 #   a count-leading-zeros when NU is the contiguous powers of two.  The
 #   reference walk's blocking pointer is provably redundant — within a
 #   round takes form a prefix of the eligibles, and u never increases —
-#   so the walk terminates on "no new takes" instead.
+#   and, as there, len(NU) rounds complete the walk.
 # * ServerFilling with pow2-contiguous NU *and* k a multiple of max(NU)
 #   (``k_mult``, a static flag the callers compute host-side) admits a
 #   closed form: capacity stays a multiple of the class need while that
@@ -1400,22 +1400,47 @@ def _srpt_init(R: int, Q: int, dt):
 # The slot table is carried as per-column arrays in their natural dtypes
 # (i32 ids/needs, bool flags) instead of one [R, Q, 8] f64 stack: the
 # integer columns feed the pack sorts without per-event casts.
+#
+# Pairwise ordering (the static ``pairwise``, Q up to a per-backend
+# _SRPT_PAIRWISE_MAX_Q).  On a TPU the sorts are cheap and the row
+# gathers around them (bisection probes, the gathers into sorted order
+# and back) are not: each is a serial element-by-element gather along
+# the lane axis.  So the step can stay in slot order.  Each order above
+# is computed as per-slot counts of the slots ordered before it, with
+# dense [R, Q, Q] compares and int32 sums (``_srpt_count_before``): the
+# collapsed rank r1 = #{rk_j < rk_i} is the bisection's result, the
+# position #{pack_j < pack_i} of the unique (r1, arrival rank, slot)
+# pack key is the stable sort's inverse permutation, and the walk's
+# inclusive prefix sum in packing order is the sum of w_j over the slots
+# at or before slot i in that order.  The walk's rounds, the closed form
+# and every output are unchanged, so the two orderings are bit-identical;
+# ``take`` comes out in slot order and needs no unsort.  The pairwise
+# form costs O(Q^2) per lane and event.
 # --------------------------------------------------------------------------
 
 
-def _srpt_ff_walk(Fi0, need_w, cand, NU: tuple, NUi):
+def _srpt_ff_walk(Fi0, need_w, cand, NU: tuple, NUi, prefix=None):
     """Integer first-fit walk: bit-equal to :func:`_srpt_first_fit` on
     integer needs/capacities (see section comment for the argument).
 
     ``Fi0`` [R] i32 is floor(k); ``need_w`` [R, Q] i32 the candidate
-    needs in packing order (0 for empty); ``cand`` the candidate mask.
+    needs (0 for empty); ``cand`` the candidate mask.  ``prefix`` maps an
+    [R, Q] i32 row to its inclusive prefix sums in packing order; the
+    default, a cumsum along the row, means the rows are already in
+    packing order.
     """
+    if prefix is None:
+        prefix = partial(jnp.cumsum, axis=1, dtype=jnp.int32)
     R, Q = need_w.shape
     pow2 = tuple(NU) == tuple(2 ** i for i in range(len(NU)))
     maxnu = int(max(NU))
-
-    def body(st):
-        take, F, _ = st
+    # Over the rounds that take anything, u falls strictly through the
+    # values of NU (a round either takes every eligible job or leaves
+    # F < u), and a round that takes nothing changes nothing: len(NU)
+    # rounds complete every lane, as in the reference walk.  A fixed
+    # count keeps the step's cost independent of the data.
+    take, F = jnp.zeros((R, Q), bool), Fi0
+    for _ in range(len(NU)):
         if pow2:
             # largest NU <= F is min(2^msb(F), max NU) when NU is the
             # contiguous powers of two
@@ -1427,20 +1452,50 @@ def _srpt_ff_walk(Fi0, need_w, cand, NU: tuple, NUi):
                           dtype=jnp.int32)
             u = jnp.where(cnt > 0, jnp.take(NUi, jnp.clip(cnt - 1, 0)), 0)
         elig = cand & ~take & (need_w <= u[:, None])
-        csum = jnp.cumsum(jnp.where(elig, need_w, 0), axis=1,
-                          dtype=jnp.int32)
+        csum = prefix(jnp.where(elig, need_w, 0))
         # Within a round F - (csum - need) is nonincreasing along the row,
         # so takes are a prefix of the eligible set; with u nonincreasing
         # across rounds no skipped job regains eligibility, which makes
         # the reference walk's blocking pointer a no-op.
         newt = elig & (F[:, None] - (csum - need_w) >= u[:, None])
         take = take | newt
-        d = jnp.sum(jnp.where(newt, need_w, 0), axis=1, dtype=jnp.int32)
-        return take, F - d, d.sum() > 0
+        F = F - jnp.sum(jnp.where(newt, need_w, 0), axis=1, dtype=jnp.int32)
+    return take
 
-    st = (jnp.zeros((R, Q), bool), Fi0, jnp.asarray(True))
-    st = jax.lax.while_loop(lambda s: s[2], body, st)
-    return st[0]
+
+#: Per backend, the largest slot table (the static ``Q``) that the fast
+#: step orders by pairwise precedence counts; larger tables, and the
+#: backends not listed, sort.  The pairwise form costs O(Q^2) per lane
+#: and event, the sorts O(Q log^2 Q) plus row gathers.  Measured on one
+#: ``_srpt_scan_batch`` call of each form (PERF.md, "Where the time
+#: goes"): on XLA:CPU pairwise wins at Q <= 128 and loses from 256 on; on
+#: a TPU v5e it wins at every Q measured, 256 to 16384.
+_SRPT_PAIRWISE_MAX_Q = {"cpu": 128, "tpu": 16384}
+
+
+def _srpt_pairwise(Q: int) -> bool:
+    """Whether the fast SRPT step orders a ``Q``-slot table pairwise on
+    the default backend: the callers' static ``pairwise`` argument."""
+    return Q <= _SRPT_PAIRWISE_MAX_Q.get(jax.default_backend(), 0)
+
+
+def _srpt_count_before(key, w=None, inclusive=False):
+    """Per lane and slot ``i``: the sum of ``w`` (a count when None) over
+    the slots ``j`` that ``key`` orders before ``i``, and ``i`` itself if
+    ``inclusive``.
+
+    One dense compare-and-reduce over an [R, Q, Q] block (``j`` on the
+    reduced axis 1, ``i`` minor), in int32: no sort and no gather, slot
+    order in and out.  Over a key unique per slot, the exclusive count is
+    the slot's position in the key's ascending order and the inclusive
+    sum the prefix sum of ``w`` in that order.
+    """
+    kj, ki = key[:, :, None], key[:, None, :]
+    before = (kj <= ki) if inclusive else (kj < ki)
+    if w is None:
+        return jnp.sum(before, axis=1, dtype=jnp.int32)
+    return jnp.sum(jnp.where(before, w[:, :, None], 0), axis=1,
+                   dtype=jnp.int32)
 
 
 def _srpt_fast_init(R: int, Q: int, dt):
@@ -1463,13 +1518,16 @@ def _srpt_fast_init(R: int, Q: int, dt):
 
 
 def _srpt_fast_make_step(jobrec, kk, Q: int, NU: tuple, sf: bool,
-                         j_live=None, k_mult: bool = False):
+                         j_live=None, k_mult: bool = False,
+                         pairwise: bool = False):
     """Op-lean SRPT event step, bit-identical to :func:`_srpt_make_step`.
 
     Same inputs as the reference factory plus ``k_mult``, the static
     "every lane's k is an integer multiple of max(NU)" flag enabling the
-    closed-form ServerFilling walk (see the section comment).  The carry
-    is the :func:`_srpt_fast_init` per-column layout.
+    closed-form ServerFilling walk, and ``pairwise``, the static choice
+    of the pairwise ordering over the sorts (see the section comment;
+    :func:`_srpt_pairwise` makes it).  The carry is the
+    :func:`_srpt_fast_init` per-column layout.
     """
     R, J, _ = jobrec.shape
     dt = jobrec.dtype
@@ -1513,6 +1571,8 @@ def _srpt_fast_make_step(jobrec, kk, Q: int, NU: tuple, sf: bool,
         lut[int(v)] = i
     lut = jnp.asarray(lut)
     assert max(1, int(np.ceil(np.log2(NCLS + 1)))) + bQ <= 32
+    assert not (pairwise and sf) or (maxneed + 1) * Q < 2 ** 31, \
+        "the ServerFilling walk key (maxneed - need) * Q + pos is int32"
 
     def taa(a, idx):
         return jnp.take_along_axis(a, idx[:, None], axis=1)[:, 0]
@@ -1536,6 +1596,115 @@ def _srpt_fast_make_step(jobrec, kk, Q: int, NU: tuple, sf: bool,
             step >>= 1
         sv = jnp.take_along_axis(srt, jnp.minimum(lo, Q - 1), axis=1)
         return lo + jnp.where((lo < Q) & (sv < v), 1, 0)
+
+    def pack_key(r1, abr):
+        # (collapsed rank, arrival rank, slot) in one word: unique per
+        # slot, and its order is the reference's stable (rank, arrival)
+        # sort
+        return ((r1.astype(packdt) << (bJ + bQ))
+                | (abr.astype(packdt) << bQ) | iota_u.astype(packdt))
+
+    def class_ends(cand, nd):
+        # Closed-form ServerFilling (NU contiguous powers of two and k a
+        # multiple of max(NU)): capacity stays a multiple of the class
+        # need while that class is walked, so the threshold rounds
+        # converge to the per-class greedy count min(cnt_c, F_c // c).
+        # Returns [R, NCLS], classes by descending need: where each
+        # class's takes end in the packing order.
+        onec = cand[:, :, None] & (nd[:, :, None] == NUi[None, None, ::-1])
+        cnt_c = jnp.sum(onec, axis=1, dtype=jnp.int32)
+        lims = []
+        F = Fi0
+        for c in range(NCLS):
+            nu_c = int(NU[NCLS - 1 - c])
+            lim = jnp.minimum(cnt_c[:, c], F // nu_c)
+            F = F - lim * nu_c
+            lims.append(lim)
+        start_t = jnp.cumsum(cnt_c, axis=1, dtype=jnp.int32) - cnt_c
+        return start_t + jnp.stack(lims, axis=1)
+
+    def desired_sorted(rk, abr, need, occ):
+        # single-operand sort of the rank keys + bisection collapses them
+        # to integers, then one pack sort on (rank', arrival rank, slot)
+        # yields the stable permutation
+        srt = jax.lax.sort((rk,), dimension=1, num_keys=1)[0]
+        ps = jax.lax.sort((pack_key(bsearch(srt, rk), abr),), dimension=1,
+                          num_keys=1)[0]
+        perm = (ps & (Q - 1)).astype(jnp.int32)
+        need_s = jnp.take_along_axis(need, perm, axis=1)
+        occ_s = need_s >= 1
+        if not sf:
+            return unsort(perm, _srpt_ff_walk(Fi0, need_s, occ_s, NU, NUi))
+        cum = jnp.cumsum(jnp.where(occ_s, need_s, 0), axis=1,
+                         dtype=jnp.int32)
+        has_m = cum[:, -1] >= kceil
+        idx_m = jnp.argmax(cum >= kceil[:, None], axis=1)
+        in_M = occ_s & (pos <= idx_m[:, None])
+        if pay2:
+            # key = descending-need class (maxneed - need; non-M last);
+            # payload need/in_M/rank ride along so no post-sort gathers.
+            # Non-M entries reorder by need, which is sound: they are
+            # never eligible, so take and missed are identically zero
+            # there.
+            key2 = jnp.where(in_M, maxneed - need_s,
+                             maxneed + 1).astype(jnp.uint32)
+            pack2 = ((key2 << (bN + 1 + bQ))
+                     | (need_s.astype(jnp.uint32) << (1 + bQ))
+                     | (in_M << bQ) | iota_u)
+            ps2 = jax.lax.sort((pack2,), dimension=1, num_keys=1)[0]
+            need_w = ((ps2 >> (1 + bQ)) & ((1 << bN) - 1)).astype(jnp.int32)
+            cand_w = ((ps2 >> bQ) & 1) == 1
+            perm2 = (ps2 & (Q - 1)).astype(jnp.int32)
+            slot_w = jnp.take_along_axis(perm, perm2, axis=1)
+        else:
+            cls = jnp.where(in_M, jnp.take(lut, need_s),
+                            NCLS).astype(jnp.uint32)
+            ps2 = jax.lax.sort(((cls << bQ) | iota_u,), dimension=1,
+                               num_keys=1)[0]
+            perm2 = (ps2 & (Q - 1)).astype(jnp.int32)
+            need_w = jnp.take_along_axis(need_s, perm2, axis=1)
+            slot_w = jnp.take_along_axis(perm, perm2, axis=1)
+            cand_w = jnp.take_along_axis(in_M, perm2, axis=1)
+        if closed_sf:
+            clsw = NCLS - 1 - (31 - jax.lax.clz(jnp.maximum(need_w, 1)))
+            endp = jnp.take_along_axis(class_ends(cand_w, need_w),
+                                       jnp.clip(clsw, 0, NCLS - 1), axis=1)
+            take = cand_w & (pos < endp)
+        else:
+            take = _srpt_ff_walk(Fi0, need_w, cand_w, NU, NUi)
+        return jnp.where(has_m[:, None], unsort(slot_w, take), occ)
+
+    def desired_pairwise(rk, abr, need, occ):
+        # The same orders as counts of the slots ordered before each
+        # slot, all in slot order: nothing is permuted, so nothing is
+        # gathered back.  r1 is bsearch(sort(rk), rk) (the same float
+        # compare: -0.0 ties +0.0, INF marks an empty slot); the pack
+        # key is unique per slot, so spos is the stable sort's inverse
+        # permutation.
+        spos = _srpt_count_before(pack_key(_srpt_count_before(rk), abr))
+        cand = need >= 1
+        if not sf:
+            return _srpt_ff_walk(
+                Fi0, need, cand, NU, NUi,
+                prefix=partial(_srpt_count_before, spos, inclusive=True))
+        w = jnp.where(cand, need, 0)
+        # in M: the need ranked strictly before a slot is below k (the
+        # sorted cumsum has not reached k before it)
+        in_M = cand & (_srpt_count_before(spos, w) < kceil[:, None])
+        has_m = jnp.sum(w, axis=1) >= kceil
+        # the walk order of M: descending need, then rank order
+        key2 = (maxneed - need) * Q + spos
+        if closed_sf:
+            ends = class_ends(in_M, need)
+            endp = sum(jnp.where(need == int(v), ends[:, c:c + 1], 0)
+                       for c, v in enumerate(sorted(NU, reverse=True)))
+            at = _srpt_count_before(key2, in_M.astype(jnp.int32))
+            take = in_M & (at < endp)
+        else:
+            take = _srpt_ff_walk(
+                Fi0, need, in_M, NU, NUi,
+                prefix=partial(_srpt_count_before, key2, inclusive=True))
+        return jnp.where(has_m[:, None], take, occ)
 
     def step(carry, _):
         ai, cols, ovf, npre, ne, peak = carry
@@ -1587,80 +1756,8 @@ def _srpt_fast_make_step(jobrec, kk, Q: int, NU: tuple, sf: bool,
             run, jnp.maximum(0.0, rem - (t[:, None] - rs)), rem)
         rank = cur_rem * need.astype(dt) if sf else cur_rem
         rk = jnp.where(occ, rank, INF)
-        # single-operand sort of the rank keys + bisection collapses them
-        # to integers, then one pack sort on (rank', arrival rank, slot)
-        # yields the stable permutation
-        srt = jax.lax.sort((rk,), dimension=1, num_keys=1)[0]
-        r1 = bsearch(srt, rk)
-        abi = abr.astype(packdt)
-        pack = ((r1.astype(packdt) << (bJ + bQ)) | (abi << bQ)
-                | iota_u.astype(packdt))
-        ps = jax.lax.sort((pack,), dimension=1, num_keys=1)[0]
-        perm = (ps & (Q - 1)).astype(jnp.int32)
-        need_s = jnp.take_along_axis(need, perm, axis=1)
-        occ_s = need_s >= 1
-        if sf:
-            cum = jnp.cumsum(jnp.where(occ_s, need_s, 0), axis=1,
-                             dtype=jnp.int32)
-            has_m = cum[:, -1] >= kceil
-            idx_m = jnp.argmax(cum >= kceil[:, None], axis=1)
-            in_M = occ_s & (pos <= idx_m[:, None])
-            if pay2:
-                # key = descending-need class (maxneed - need; non-M
-                # last); payload need/in_M/rank ride along so no
-                # post-sort gathers.  Non-M entries reorder by need,
-                # which is sound: they are never eligible, so take and
-                # missed are identically zero there.
-                key2 = jnp.where(in_M, maxneed - need_s,
-                                 maxneed + 1).astype(jnp.uint32)
-                pack2 = ((key2 << (bN + 1 + bQ))
-                         | (need_s.astype(jnp.uint32) << (1 + bQ))
-                         | (in_M << bQ) | iota_u)
-                ps2 = jax.lax.sort((pack2,), dimension=1, num_keys=1)[0]
-                need_w = ((ps2 >> (1 + bQ))
-                          & ((1 << bN) - 1)).astype(jnp.int32)
-                cand_w = ((ps2 >> bQ) & 1) == 1
-                perm2 = (ps2 & (Q - 1)).astype(jnp.int32)
-                slot_w = jnp.take_along_axis(perm, perm2, axis=1)
-            else:
-                cls = jnp.where(in_M, jnp.take(lut, need_s),
-                                NCLS).astype(jnp.uint32)
-                pack2 = (cls << bQ) | iota_u
-                ps2 = jax.lax.sort((pack2,), dimension=1, num_keys=1)[0]
-                perm2 = (ps2 & (Q - 1)).astype(jnp.int32)
-                need_w = jnp.take_along_axis(need_s, perm2, axis=1)
-                slot_w = jnp.take_along_axis(perm, perm2, axis=1)
-                cand_w = jnp.take_along_axis(in_M, perm2, axis=1)
-            if closed_sf:
-                # NU contiguous powers of two and k a multiple of
-                # max(NU): capacity stays a multiple of the class need
-                # while that class is walked, so the threshold rounds
-                # converge to the per-class greedy count
-                # min(cnt_c, F_c // c).
-                onec = cand_w[:, :, None] & (
-                    need_w[:, :, None] == NUi[None, None, ::-1])
-                cnt_c = jnp.sum(onec, axis=1, dtype=jnp.int32)  # desc
-                lims = []
-                F = Fi0
-                for c in range(NCLS):
-                    nu_c = int(NU[NCLS - 1 - c])
-                    lim = jnp.minimum(cnt_c[:, c], F // nu_c)
-                    F = F - lim * nu_c
-                    lims.append(lim)
-                lim_t = jnp.stack(lims, axis=1)
-                start_t = jnp.cumsum(cnt_c, axis=1, dtype=jnp.int32) - cnt_c
-                end_t = start_t + lim_t
-                clsw = (NCLS - 1
-                        - (31 - jax.lax.clz(jnp.maximum(need_w, 1))))
-                endp = jnp.take_along_axis(
-                    end_t, jnp.clip(clsw, 0, NCLS - 1), axis=1)
-                take = cand_w & (pos < endp)
-            else:
-                take = _srpt_ff_walk(Fi0, need_w, cand_w, NU, NUi)
-            desired = jnp.where(has_m[:, None], unsort(slot_w, take), occ)
-        else:
-            take = _srpt_ff_walk(Fi0, need_s, occ_s, NU, NUi)
-            desired = unsort(perm, take)
+        desired = (desired_pairwise if pairwise else desired_sorted)(
+            rk, abr, need, occ)
 
         to_pre = active[:, None] & run & ~desired
         to_start = active[:, None] & desired & ~run
@@ -1678,7 +1775,7 @@ def _srpt_fast_make_step(jobrec, kk, Q: int, NU: tuple, sf: bool,
 
 def _srpt_stream_core(arrival, need, service, kk, carry, Q: int, NU: tuple,
                       sf: bool, length: int, j_live=None,
-                      k_mult: bool = False):
+                      k_mult: bool = False, pairwise: bool = False):
     """``length`` SRPT event steps resumed from ``carry``, batched.
 
     Runs the fast step (``carry`` is the :func:`_srpt_fast_init` layout).
@@ -1688,14 +1785,14 @@ def _srpt_stream_core(arrival, need, service, kk, carry, Q: int, NU: tuple,
     """
     jobrec = jnp.stack([arrival, service, need], axis=2)
     step = _srpt_fast_make_step(jobrec, kk, Q, NU, sf, j_live=j_live,
-                                k_mult=k_mult)
+                                k_mult=k_mult, pairwise=pairwise)
     carry, (job_ev, t_ev, fs_ev) = jax.lax.scan(step, carry, None,
                                                 length=length)
     return carry, job_ev.T, t_ev.T, fs_ev.T
 
 
 def _srpt_core(arrival, need, service, kk, Q: int, NU: tuple, sf: bool,
-               k_mult: bool = False):
+               k_mult: bool = False, pairwise: bool = False):
     """Full-trace SRPT event scan: 2J steps from an empty system.
 
     Returns the event streams plus the per-lane (ovf, npre, ne, peak)
@@ -1707,7 +1804,7 @@ def _srpt_core(arrival, need, service, kk, Q: int, NU: tuple, sf: bool,
     carry0 = _srpt_fast_init(R, Q, arrival.dtype)
     carry, job_ev, t_ev, fs_ev = _srpt_stream_core(
         arrival, need, service, kk, carry0, Q, NU, sf, 2 * J,
-        k_mult=k_mult)
+        k_mult=k_mult, pairwise=pairwise)
     return job_ev, t_ev, fs_ev, carry[2], carry[3], carry[4], carry[5]
 
 
@@ -1738,7 +1835,8 @@ def _srpt_args(trace_or_batch, queue_cap) -> int:
     exceeds it, which raises loudly (``_srpt_check_ovf``) instead of
     returning a silently wrong path.  The default ``min(J, max(4k, 256))``
     comfortably bounds any stable workload; per-step cost grows with
-    ``Q log Q`` (the rank sorts), so it is deliberately not ``J``.  The
+    ``Q`` (as ``Q^2`` in the pairwise ordering of the fast step), so it
+    is deliberately not ``J``.  The
     result is rounded up to a power of two: the slot-index pack keys of
     the fast step and the bitonic network of the Pallas kernels both
     need it, and results are Q-independent below the overflow bound.

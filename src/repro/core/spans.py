@@ -19,8 +19,12 @@ on the device), ``repro.run`` (dispatch until the program is done),
 ``repro.fetch`` (outputs to the host) and ``repro.assemble`` (overflow
 checks, event-to-job scatters, the result), all with the call's
 ``call=<n>``.  Its counters: ``fetch_bytes`` (sum of the bytes fetched),
-``srpt_peak`` (the largest in-system job count an SRPT scan saw) and
-``srpt_q`` (the largest slot-table size Q it ran with).
+``srpt_peak`` (the largest in-system job count an SRPT scan saw),
+``srpt_q`` (the largest slot-table size Q it ran with) and
+``srpt_pairwise_events`` (sum of the event steps, 2J per replication,
+that the ``jax`` and ``jax-shard`` SRPT scans ran with the slot table
+ordered by pairwise precedence counts, at Q up to the backend's
+``sim_jax._SRPT_PAIRWISE_MAX_Q``; absent when every scan sorted).
 """
 
 from __future__ import annotations

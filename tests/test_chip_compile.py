@@ -23,7 +23,7 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
 
 from repro.core import shard, sim_batch
 from repro.core.partition import balanced_partition
-from repro.core.sim_jax import _srpt_args
+from repro.core.sim_jax import _SRPT_PAIRWISE_MAX_Q, _srpt_args
 from repro.core.workload import BatchTrace, figure1_workload
 from repro.data.swf import sdsc_sp2_trace
 
@@ -98,20 +98,30 @@ def test_bs_fcfs_batch_compiles_for_v5e(one_chip, no_compile_cache):
     _fits_one_chip(compiled)
 
 
-@pytest.mark.parametrize("sf", (False, True), ids=("ff-srpt", "sf-srpt"))
-def test_srpt_batch_compiles_for_v5e(sf, one_chip, no_compile_cache):
-    """The fast SRPT step sorts its f64 rank keys directly: the chip's f64
-    emulation refuses the u64 bitcast an earlier version sorted."""
+@pytest.mark.parametrize(
+    "sf,Q,pairwise",
+    ((False, None, True), (True, None, True), (False, None, False),
+     (False, _SRPT_PAIRWISE_MAX_Q["tpu"], True)),
+    ids=("ff-srpt", "sf-srpt", "ff-srpt-sorted", "ff-srpt-largest-pairwise"))
+def test_srpt_batch_compiles_for_v5e(sf, Q, pairwise, one_chip,
+                                     no_compile_cache):
+    """Both orderings of the fast SRPT step compile at the smoke shape's
+    Q (None), and the pairwise one fits at the largest Q the chip runs it
+    with: pairwise counts, or sorts whose f64 rank keys are sorted
+    directly (the chip's f64 emulation refuses the u64 bitcast an
+    earlier version sorted)."""
     batch = BatchTrace.from_trace(sdsc_sp2_trace(SJ, k=SK, load=0.85,
                                                  seed=0),
                                   SR, seed=0, method="block")
     NU = sim_batch._srpt_nu(batch)
+    Q = Q or _srpt_args(batch, SQ)
     with enable_x64():
         f64 = _sds((SR, SJ), np.float64, one_chip)
         compiled = sim_batch._srpt_scan_batch.lower(
             f64, f64, f64, _sds((SR,), np.float64, one_chip),
-            Q=_srpt_args(batch, SQ), NU=NU, sf=sf,
-            k_mult=sim_batch._srpt_k_mult(NU, batch)).compile()
+            Q=Q, NU=NU, sf=sf,
+            k_mult=sim_batch._srpt_k_mult(NU, batch),
+            pairwise=pairwise).compile()
     _fits_one_chip(compiled)
 
 

@@ -19,6 +19,7 @@ import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st
 
+import jax
 import jax.numpy as jnp
 from jax import enable_x64
 
@@ -29,7 +30,8 @@ from repro.core.sim_batch import (bs_sim_batch, fcfs_sim_batch,
 from repro.core.sim_jax import bs_sim, fcfs_sim, loss_queue_sim, \
     modified_bs_sim
 from repro.core.simulator import Simulation
-from repro.core.workload import Exp, JobClass, Workload, figure1_workload
+from repro.core.workload import (BatchTrace, Exp, JobClass, Workload,
+                                 figure1_workload)
 
 
 def small_workload(k=24, load=0.85):
@@ -261,6 +263,118 @@ def test_registry_fast_engines_bitexact_vs_jax(k):
                     assert np.array_equal(a, b2), (policy, eng, f)
             checked += 1
     assert checked >= 10   # 5 jax policies x {jax-shard, pallas}
+
+
+# -- SRPT fast step: both slot orderings, rtol=0 ---------------------------
+#
+# The fast step orders the slot table by pairwise precedence counts when
+# Q <= the backend's _SRPT_PAIRWISE_MAX_Q and by sorts above it.  Each
+# case moves that crossover so that its Q lies on the side it names, and
+# pins the raw event streams and counters of the fast scan to the
+# reference step's, and the engine's results to the python oracle's.  On
+# the tie-heavy trace the oracle is left out: it breaks exact (rank,
+# arrival) ties and equal completion times by its own set and heap
+# order, which no scan engine follows; its hand-built tie cases are in
+# ``test_policies.py``.
+
+
+def _srpt_classes(k, needs=(1, 2, 4, 8)):
+    classes = tuple(JobClass(f"n{n}", n, Exp(float(n)), 1.0 / len(needs))
+                    for n in needs)
+    return Workload(k=k, lam=1.0, classes=classes).with_load(0.85)
+
+
+def _srpt_tie_batch(J, k=13, needs=(1, 3, 5), R=2, seed=2):
+    """Jobs arrive in pairs at one instant, and services come from a pool
+    of 16 values: ranks tie within and across arrival times."""
+    rng = np.random.default_rng(seed)
+    nd = rng.choice(needs, (R, J))
+    pool = rng.exponential(2.0, 16)
+    lam = 0.8 * k / (np.mean(needs) * pool.mean())
+    t = np.cumsum(rng.exponential(2.0 / lam, (R, J // 2)), axis=1)
+    return BatchTrace(arrival=np.repeat(t, 2, axis=1),
+                      cls=np.zeros((R, J), np.int64),
+                      service=rng.choice(pool, (R, J)),
+                      need=nd.astype(np.int64), k=k, C=1)
+
+
+def _srpt_batch(trace, J, R):
+    if trace == "ties":
+        return _srpt_tie_batch(J, R=R)
+    k = 32 if trace == "k-mult" else 30     # max need 8 divides k or not
+    return _srpt_classes(k).sample_traces(J, R, seed=J)
+
+
+def _srpt_reference_streams(batch, Q, sf):
+    """Raw streams of the reference step (``_srpt_make_step``)."""
+    from repro.core.sim_batch import _srpt_nu
+    with enable_x64():
+        a, n, v = (jnp.asarray(x, jnp.float64)
+                   for x in (batch.arrival, batch.need, batch.service))
+        kk = jnp.full(batch.reps, float(batch.k), jnp.float64)
+        step = sim_jax._srpt_make_step(jnp.stack([a, v, n], axis=2), kk, Q,
+                                       _srpt_nu(batch), sf)
+        carry, ev = jax.jit(lambda c: jax.lax.scan(
+            step, c, None, length=2 * batch.num_jobs))(
+                sim_jax._srpt_init(batch.reps, Q, jnp.float64))
+        return [np.asarray(x).T for x in ev] + [np.asarray(x)
+                                                for x in carry[2:]]
+
+
+def _srpt_fast_streams(batch, Q, sf, pairwise):
+    from repro.core.sim_batch import _srpt_k_mult, _srpt_nu
+    NU = _srpt_nu(batch)
+    with enable_x64():
+        a, n, v = (jnp.asarray(x, jnp.float64)
+                   for x in (batch.arrival, batch.need, batch.service))
+        kk = jnp.full(batch.reps, float(batch.k), jnp.float64)
+        out = jax.jit(sim_jax._srpt_core, static_argnums=(4, 5, 6, 7, 8))(
+            a, n, v, kk, Q, NU, sf, _srpt_k_mult(NU, batch), pairwise)
+        return [np.asarray(x) for x in out]
+
+
+@pytest.mark.parametrize("side", ("pairwise", "sort"))
+@pytest.mark.parametrize("trace", ("k-mult", "not-k-mult", "ties", "grid"))
+@pytest.mark.parametrize("policy", ("ff-srpt", "sf-srpt"))
+def test_srpt_fast_step_bitexact_both_sides_of_crossover(policy, trace,
+                                                         side, monkeypatch):
+    from repro.core import engines
+    from repro.core.sim_jax import _srpt_args, _srpt_pairwise
+
+    J, R, cap = 240, 2, 256
+    pairwise = side == "pairwise"
+    monkeypatch.setattr(sim_jax, "_SRPT_PAIRWISE_MAX_Q",
+                        {jax.default_backend(): cap if pairwise else cap // 2})
+    sf = policy == "sf-srpt"
+    if trace == "grid":
+        # two cells of unequal J: the grid pads J, and j_live stops the
+        # shorter cell's lane at its own 2J events
+        wl = _srpt_classes(32)
+        cells = [engines.GridCell(wl.sample_traces(J, R, seed=3),
+                                  queue_cap=cap),
+                 engines.GridCell(wl.sample_traces(J - 40, R, seed=4),
+                                  queue_cap=cap)]
+        assert _srpt_pairwise(_srpt_args(cells[0].batch, cap)) == pairwise
+        for c, out in zip(cells, engines.simulate_grid(policy, cells,
+                                                       engine="jax")):
+            ref = engines.simulate(policy, c.batch, engine="python")
+            for f in ("response", "wait", "start", "preemptions"):
+                assert np.array_equal(getattr(out, f), getattr(ref, f)), f
+        return
+    batch = _srpt_batch(trace, J, R)
+    Q = _srpt_args(batch, cap)
+    assert _srpt_pairwise(Q) == pairwise
+    fast = _srpt_fast_streams(batch, Q, sf, pairwise)
+    ref = _srpt_reference_streams(batch, Q, sf)
+    for i, (x, y) in enumerate(zip(fast, ref)):
+        assert np.array_equal(x, y), (i, x, y)
+    assert fast[4].sum() > 0                  # the scans preempted
+    if trace == "ties":
+        return
+    oracle = engines.simulate(policy, batch, engine="python")
+    out = engines.simulate(policy, batch, engine="jax", queue_cap=cap)
+    for f in ("response", "wait", "start", "preemptions"):
+        assert np.array_equal(getattr(out, f), getattr(oracle, f)), f
 
 
 def test_pallas_kernel_family_matches_refs_at_raw_stream_level():

@@ -128,9 +128,18 @@ def _peak_in_system(result, batch) -> int:
     return peak
 
 
+@pytest.mark.parametrize("side", ("pairwise", "sorted"))
 @pytest.mark.parametrize("policy,engine",
                          [c for c in CORES if c[0].endswith("srpt")])
-def test_srpt_counters_are_the_oracle_peak(policy, engine):
+def test_srpt_counters_are_the_oracle_peak(policy, engine, side,
+                                           monkeypatch):
+    """``srpt_peak`` is the oracle's in-system peak on either side of the
+    pairwise crossover; ``srpt_pairwise_events`` counts the 2J events of
+    every replication below it and is absent above it."""
+    from repro.core import sim_jax
+    monkeypatch.setattr(sim_jax, "_SRPT_PAIRWISE_MAX_Q",
+                        {jax.default_backend(): 64 if side == "pairwise"
+                         else 32})
     wl = _workload(load=0.9)
     batch = wl.sample_traces(250, 3, seed=2)
     oracle = engines.simulate(policy, batch, engine="python")
@@ -140,6 +149,10 @@ def test_srpt_counters_are_the_oracle_peak(policy, engine):
     assert c["srpt_q"] == 64
     assert c["srpt_peak"] <= c["srpt_q"]
     assert c["srpt_peak"] == _peak_in_system(oracle, batch)
+    if side == "pairwise":
+        assert c["srpt_pairwise_events"] == 2 * 250 * 3
+    else:
+        assert "srpt_pairwise_events" not in c
 
 
 def test_counters_sum_high_copy_reset():
