@@ -21,6 +21,7 @@ import json
 import os
 import sys
 import time
+from types import SimpleNamespace
 
 BENCH = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH)
@@ -66,7 +67,9 @@ def main(argv=None) -> int:
         ref_s = time.perf_counter() - t1
         line = {"seed": seed, "call_s": call_s, "reference_s": ref_s,
                 "program": compare.readings(got, want, horizon)}
-        low = compare.reference_answers(ref, cell.config, rows, np.float32)
+        # the control answers through the path, as the program does
+        low = path.answers(SimpleNamespace(**compare.reference_answers(
+            ref, cell.config, rows, np.float32)), list(range(len(sel))))
         line["control"] = compare.readings(low, want, horizon)
         program.append(line["program"])
         control.append(line["control"])

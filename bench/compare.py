@@ -79,30 +79,46 @@ def _rel(a: float, b: float) -> float:
 def reference_answers(reference, config: dict, rows: dict, dtype) -> dict:
     """The reference's answers for the replications in ``rows`` (arrays of
     the inputs, one row per replication)."""
-    waits, pre = [], []
+    waits, pre, helper = [], [], []
     for r in range(len(rows["arrival"])):
         out = reference.simulate(rows["arrival"][r], rows["cls"][r],
                                  rows["need"][r], rows["service"][r],
                                  config, dtype)
         waits.append(out["wait"])
         pre.append(out["preemptions"])
+        helper.append(out.get("helper"))
     wait = np.stack(waits)
     return {"wait": wait, "mean_wait": wait.mean(axis=1),
+            "var_wait": wait.var(axis=1),
             "p_wait": (wait > WAIT_EPS).mean(axis=1),
+            "p_helper": (None if helper[0] is None
+                         else np.stack(helper).mean(axis=1)),
             "preemptions": None if pre[0] is None else np.array(pre)}
+
+
+def _max_rel(got, ref) -> float:
+    return max(_rel(float(g), float(r)) for g, r in zip(got, ref))
 
 
 def readings(got: dict, ref: dict, horizon: np.ndarray) -> dict:
     """The numbers compared: the program's answers ``got`` against the
-    reference's ``ref``, row by row."""
-    out = {
-        "wait_gap": max(_gap(g, r, h) for g, r, h in
-                        zip(got["wait"], ref["wait"], horizon)),
-        "mean_wait_gap": max(_rel(float(g), float(r)) for g, r in
-                             zip(got["mean_wait"], ref["mean_wait"])),
-        "p_wait_gap": _gap(got["p_wait"], ref["p_wait"]),
-    }
-    if ref["preemptions"] is not None:
+    reference's ``ref``, row by row.  Each number is read only where
+    ``got`` holds its answer (and the reference gives one)."""
+    out = {}
+    if "wait" in got:
+        out["wait_gap"] = max(_gap(g, r, h) for g, r, h in
+                              zip(got["wait"], ref["wait"], horizon))
+    if "mean_wait" in got:
+        out["mean_wait_gap"] = _max_rel(got["mean_wait"], ref["mean_wait"])
+    if "var_wait" in got:
+        out["var_wait_gap"] = _max_rel(got["var_wait"], ref["var_wait"])
+    if "p_wait" in got:
+        out["p_wait_gap"] = _gap(got["p_wait"], ref["p_wait"])
+    if "p_helper" in got and ref["p_helper"] is not None:
+        out["p_helper_gap"] = (_gap(got["p_helper"], ref["p_helper"])
+                               if got["p_helper"] is not None
+                               else math.inf)
+    if "preemptions" in got and ref["preemptions"] is not None:
         out["preempt_gap"] = (_gap(got["preemptions"], ref["preemptions"])
                               if got["preemptions"] is not None
                               else math.inf)
@@ -110,12 +126,16 @@ def readings(got: dict, ref: dict, horizon: np.ndarray) -> dict:
 
 
 def verdict(values: dict, limits: dict) -> tuple[bool, dict]:
-    """(correct, {name: {"value", "limit"}}) of the numbers compared."""
+    """(correct, {name: {"value", "limit"}}) of the numbers compared.  A
+    limit with no reading reads infinite: a path never passes a check by
+    answering less."""
     checks = {}
     for name, v in values.items():
         if name not in limits:
             raise KeyError(f"no limit for {name!r} in the cell's limits")
         checks[name] = {"value": v, "limit": limits[name]}
+    for name, limit in limits.items():
+        checks.setdefault(name, {"value": math.inf, "limit": limit})
     ok = all(math.isfinite(c["value"]) and c["value"] <= c["limit"]
              for c in checks.values())
     return ok, checks

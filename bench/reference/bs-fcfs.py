@@ -57,7 +57,9 @@ def partition(config: dict) -> tuple[list[int], int]:
 
 
 def simulate(arrival, cls, need, service, config: dict, dtype) -> dict:
-    """Per-job waits of one replication; BS-FCFS never preempts."""
+    """Per-job waits of one replication, and which jobs the helpers
+    served (``helper``: started outside their class block); BS-FCFS never
+    preempts."""
     slots, h = partition(config)
     as_list = (lambda x: np.asarray(x, np.float64).tolist()) \
         if dtype == np.float64 else (lambda x: list(np.asarray(x, dtype)))
@@ -115,4 +117,5 @@ def simulate(arrival, cls, need, service, config: dict, dtype) -> dict:
             helper_free[0] += n[j]
             serve_helpers(now)
     wait = np.asarray(start, dtype) - np.asarray(arrival, dtype)
-    return {"wait": wait.astype(np.float64), "preemptions": None}
+    return {"wait": wait.astype(np.float64), "preemptions": None,
+            "helper": ~np.array(in_block)}

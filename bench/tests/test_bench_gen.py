@@ -77,7 +77,8 @@ def test_reference_partition_equals_eq2(name):
 ])
 def test_reference_equals_python_oracle(policy, config):
     """The plain references give the event oracle's waits bit for bit on
-    the CPU (and its preemption counts)."""
+    the CPU (and its preemption counts, and its share of jobs the helpers
+    served)."""
     from repro.core import engines
     from repro.core.partition import balanced_partition_for
     from repro.core.workload import BatchTrace
@@ -95,3 +96,5 @@ def test_reference_equals_python_oracle(policy, config):
         np.testing.assert_array_equal(out["wait"], res.wait[r])
         if out["preemptions"] is not None:
             assert out["preemptions"] == res.preemptions[r]
+        if "helper" in out:
+            assert out["helper"].mean() == res.p_helper[r]
