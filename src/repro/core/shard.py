@@ -516,20 +516,20 @@ def _bs_jax_shard(batch, *, partition=None, wl=None, queue_cap=None,
     if failures is None:
         padded, R = _pad_batch(batch, mesh.size)
         with enable_x64():
-            tagged, rec_t, ovf = _fetch(_call(
+            tagged, rec_t, rpk = _fetch(_call(
                 _bs_shard_call, *_class_inputs(padded), jnp.asarray(slots),
                 s_max, h, q_cap, mesh), R)
-        return _bs_result(batch, tagged, rec_t, ovf, q_cap)
+        return _bs_result(batch, tagged, rec_t, rpk, q_cap)
     flr.require_drain(failures, "jax-shard")
     ft, ftgt, fup, length = _bs_fail_args(batch, failures, partition, wl)
     padded, R = _pad_batch(batch, mesh.size)
     (ft, ftgt, fup), _ = _pad_reps(mesh.size, ft, ftgt, fup)
     with enable_x64():
-        tagged, rec_t, ovf = _fetch(_call(
+        tagged, rec_t, rpk = _fetch(_call(
             _bs_fail_shard_call, *_class_inputs(padded),
             *_puts((ft, jnp.float64), (ftgt, jnp.int32), (fup, jnp.float64)),
             jnp.asarray(slots), s_max, h, q_cap, length, mesh), R)
-    return _with_drain_obs(_bs_result(batch, tagged, rec_t, ovf, q_cap),
+    return _with_drain_obs(_bs_result(batch, tagged, rec_t, rpk, q_cap),
                            batch, failures)
 
 
@@ -961,10 +961,10 @@ def _bs_grid_shard(cells, devices=None):
                 _dev(pg(p["j_live"]), jnp.int32),
                 p["C_pad"], p["s_max_pad"], p["h_pad"], p["q_cap_pad"],
                 p["length"], mesh)
-            ovf = carry[9]
+            rpk = carry[9]
         return _bs_grid_extract(cells, p, np.asarray(tagged)[:G, :R],
                                 np.asarray(rec_t)[:G, :R],
-                                np.asarray(ovf)[:G, :R])
+                                np.asarray(rpk)[:G, :R])
     p = _bs_grid_plan(cells)
     pp = dict(p, **{k: pg(p[k])
                     for k in ("st0", "comp0", "ring0", "heads0", "W0")})
@@ -980,12 +980,12 @@ def _bs_grid_shard(cells, devices=None):
             _dev(pg(p["j_live"]), jnp.int32),
             p["C_pad"], p["s_max_pad"], p["h_pad"], p["q_cap_pad"],
             2 * p["J_pad"], mesh)
-        ovf, ne = carry[8], carry[9]
+        rpk, ne = carry[8], carry[9]
     assert (np.asarray(ne) == 2 * pg(p["j_live"])).all(), \
         "BS grid scan under-ran its event budget"
     return _bs_grid_extract(cells, p, np.asarray(tagged)[:G, :R],
                             np.asarray(rec_t)[:G, :R],
-                            np.asarray(ovf)[:G, :R])
+                            np.asarray(rpk)[:G, :R])
 
 
 def _srpt_grid_shard(sf: bool, cells, devices=None):

@@ -338,32 +338,45 @@ def _modbs_result(batch: BatchTrace, blocked, starts) -> BatchSimResult:
                               p_routed=blocked.mean(axis=1), start=starts)
 
 
-def _bs_check_ovf(ovf, q_cap: int, cell: str = "") -> None:
-    ovf = np.asarray(ovf)
-    if ovf.any():
+def _bs_check_ring(rpk, q_cap: int, cell: str = "") -> None:
+    """Raise the ``bs_ring_peak`` and ``bs_ring_cap`` counters from a
+    scan's per-lane peak ring occupancy ``rpk``, and raise an error where
+    it passed the ring's ``q_cap`` slots (the ring then overwrote queued
+    jobs, so the path is not exact)."""
+    rpk = np.asarray(rpk)
+    spans.high("bs_ring_peak", rpk.max(initial=0))
+    spans.high("bs_ring_cap", q_cap)
+    over = rpk > q_cap
+    if over.any():
         raise RuntimeError(
-            f"helper-wait ring buffer overflow (queue_cap={q_cap}) in "
-            f"{cell}replication(s) {np.flatnonzero(ovf).tolist()} — "
-            f"workload unstable at this load, or raise queue_cap")
+            f"helper-wait ring buffer overflow in {cell}replication(s) "
+            f"{np.flatnonzero(over).tolist()}: up to {int(rpk.max())} jobs "
+            f"of one class waited at once, against queue_cap={q_cap} — "
+            f"the ring was too small for this run; pass queue_cap >= "
+            f"{int(rpk.max())}")
 
 
 def _bs_assemble(batch: BatchTrace, starts, served,
                  routed) -> BatchSimResult:
-    """Per-job event arrays -> BatchSimResult (one shared op order)."""
+    """Per-job event arrays -> BatchSimResult (one shared op order); the
+    jobs the helpers served add to the ``bs_helper_served`` counter, all
+    of them to ``bs_jobs``."""
+    spans.add("bs_helper_served", served.sum())
+    spans.add("bs_jobs", served.size)
     return BatchSimResult(response=starts + batch.service - batch.arrival,
                           wait=starts - batch.arrival,
                           p_helper=served.mean(axis=1), blocked=None,
                           p_routed=routed.mean(axis=1), start=starts)
 
 
-def _bs_result(batch: BatchTrace, tagged, rec_t, ovf,
+def _bs_result(batch: BatchTrace, tagged, rec_t, rpk,
                q_cap: int) -> BatchSimResult:
     with engines.call_span("repro.assemble"):
-        _bs_check_ovf(ovf, q_cap)
+        _bs_check_ring(rpk, q_cap)
         # one vectorized event->job scatter for the whole batch (no per-rep
         # Python loop: host post-processing must not scale with R)
         starts, served, routed = _bs_scatter_events(batch.num_jobs, tagged,
-                                                    rec_t)
+                                                    rec_t, batch.arrival)
         return _bs_assemble(batch, starts, served, routed)
 
 
@@ -458,26 +471,26 @@ def _bs_jax(batch: BatchTrace, *, partition=None, wl=None, queue_cap=None,
     replications axis carried natively; replication ``r`` is bit-identical
     to ``bs_sim(batch.rep(r))``.  Raises if any replication overflowed the
     per-class helper-wait ring buffers (``queue_cap``, default
-    ``min(J, 8192)``) — an overflow means the workload is unstable at this
-    load, not that the result is approximate.  With ``failures`` the scan
+    ``min(J, 8192)``): the error names the peak occupancy, and no
+    approximate result is returned.  With ``failures`` the scan
     runs the drain-mode variant (``sim_jax._bs_fail_core``).
     """
     slots, s_max, h, q_cap = _bs_args(batch, partition, wl, queue_cap)
     if failures is None:
         with enable_x64():
-            tagged, rec_t, ovf = _fetch(_call(
+            tagged, rec_t, rpk = _fetch(_call(
                 _bs_scan_batch, *_class_inputs(batch), jnp.asarray(slots),
                 s_max, h, q_cap))
-        return _bs_result(batch, tagged, rec_t, ovf, q_cap)
+        return _bs_result(batch, tagged, rec_t, rpk, q_cap)
     flr.require_drain(failures, "jax")
     ft, ftgt, fup, length = _bs_fail_args(batch, failures, partition, wl)
     with enable_x64():
-        tagged, rec_t, ovf = _fetch(_call(
+        tagged, rec_t, rpk = _fetch(_call(
             _bs_fail_scan_batch, *_class_inputs(batch),
             *_puts((ft, jnp.float64), (ftgt, jnp.int32),
                    (fup, jnp.float64)), jnp.asarray(slots), s_max, h,
             q_cap, length))
-    return _with_drain_obs(_bs_result(batch, tagged, rec_t, ovf, q_cap),
+    return _with_drain_obs(_bs_result(batch, tagged, rec_t, rpk, q_cap),
                            batch, failures)
 
 
@@ -910,19 +923,20 @@ def _bs_grid_carry(plan, lead: tuple):
             _dev(rs(plan["W0"]), jnp.float64),
             _dev(np.zeros(lead), jnp.float64),
             _dev(np.zeros(lead), jnp.float64),
-            _dev(np.zeros(lead), jnp.bool_))
+            _dev(np.zeros(lead), jnp.int32))
 
 
-def _bs_grid_extract(cells, plan, tagged, rec_t, ovf) -> list:
+def _bs_grid_extract(cells, plan, tagged, rec_t, rpk) -> list:
     tagged = np.asarray(tagged)
     rec_t = np.asarray(rec_t)
-    ovf = np.asarray(ovf)
+    rpk = np.asarray(rpk)
     J_pad = plan["J_pad"]
     out = []
     for g, c in enumerate(cells):
-        _bs_check_ovf(ovf[g], plan["q_caps"][g], cell=f"grid cell {g} ")
-        starts, served, routed = _bs_scatter_events(J_pad, tagged[g],
-                                                    rec_t[g])
+        # every cell's lanes ran the padded ring of q_cap_pad slots
+        _bs_check_ring(rpk[g], plan["q_cap_pad"], cell=f"grid cell {g} ")
+        starts, served, routed = _bs_scatter_events(
+            J_pad, tagged[g], rec_t[g], plan["arrival"][g])
         J = c.batch.num_jobs
         res = _bs_assemble(c.batch, starts[:, :J], served[:, :J],
                            routed[:, :J])
@@ -1119,11 +1133,11 @@ def _bs_grid_jax(cells):
                 _dev(p["j_live"].reshape(L), jnp.int32),
                 p["C_pad"], p["s_max_pad"], p["h_pad"], p["q_cap_pad"],
                 p["length"])
-            ovf = carry[9]
+            rpk = carry[9]
         return _bs_grid_extract(cells, p,
                                 np.asarray(tagged).reshape(G, R, -1),
                                 np.asarray(rec_t).reshape(G, R, -1),
-                                np.asarray(ovf).reshape(G, R))
+                                np.asarray(rpk).reshape(G, R))
     p = _bs_grid_plan(cells)
     with enable_x64():
         c0 = _bs_grid_carry(p, (L,))
@@ -1137,13 +1151,13 @@ def _bs_grid_jax(cells):
             _dev(p["j_live"].reshape(L), jnp.int32),
             p["C_pad"], p["s_max_pad"], p["h_pad"], p["q_cap_pad"],
             2 * p["J_pad"])
-        ovf, ne = carry[8], carry[9]
+        rpk, ne = carry[8], carry[9]
     assert (np.asarray(ne) == 2 * p["j_live"].reshape(L)).all(), \
         "BS grid scan under-ran its event budget"
     return _bs_grid_extract(cells, p,
                             np.asarray(tagged).reshape(G, R, -1),
                             np.asarray(rec_t).reshape(G, R, -1),
-                            np.asarray(ovf).reshape(G, R))
+                            np.asarray(rpk).reshape(G, R))
 
 
 def _srpt_grid(sf: bool, cells):
@@ -1778,20 +1792,26 @@ class _StreamWindow:
         self._used = need
 
     def scatter(self, tagged, rec_t, idmap, J_l: int) -> None:
-        """Scatter one chunk's [R, L] event streams (local ids -> gids)."""
+        """Scatter one chunk's [R, L] event streams (local ids -> gids).
+        A job that starts at its own arrival (never routed, or a j + 3J
+        helper commit) takes its start from the window's arrival, as in
+        ``sim_jax._bs_scatter_events``; a job's routing record never comes
+        after its start record, so routing is scattered first."""
         rows = np.broadcast_to(np.arange(self.reps)[:, None], tagged.shape)
-        m_a = (tagged >= 0) & (tagged < J_l)
         m_r = (tagged >= J_l) & (tagged < 2 * J_l)
-        m_h = tagged >= 2 * J_l
-        col = idmap[rows[m_a], tagged[m_a]] - self.base
-        self.start[rows[m_a], col] = rec_t[m_a]
-        self.known[rows[m_a], col] = True
         col = idmap[rows[m_r], tagged[m_r] - J_l] - self.base
         self.routed[rows[m_r], col] = True
-        col = idmap[rows[m_h], tagged[m_h] - 2 * J_l] - self.base
-        self.start[rows[m_h], col] = rec_t[m_h]
-        self.known[rows[m_h], col] = True
-        self.served[rows[m_h], col] = True
+        m_a = (tagged >= 0) & (tagged < J_l)
+        r, col = rows[m_a], idmap[rows[m_a], tagged[m_a]] - self.base
+        self.start[r, col] = np.where(self.routed[r, col], rec_t[m_a],
+                                      self.arr[r, col])
+        self.known[r, col] = True
+        for lo, at_arrival in ((2 * J_l, False), (3 * J_l, True)):
+            m_h = (tagged >= lo) & (tagged < lo + J_l)
+            r, col = rows[m_h], idmap[rows[m_h], tagged[m_h] - lo] - self.base
+            self.start[r, col] = self.arr[r, col] if at_arrival else rec_t[m_h]
+            self.known[r, col] = True
+            self.served[r, col] = True
 
     def fold_into(self, acc: StreamAccumulator) -> None:
         """Fold every job below the oldest still-unknown start."""
@@ -1864,8 +1884,8 @@ def _bs_inflate(canon: dict, chunk: BatchTrace, fed: int, slots,
     order the scan's min-of-heads FIFO selection relies on), zero padding
     up to B, the chunk's jobs at [B, B + Jc).  Per-class rings rebuild
     from the pending set (head counter 0), the arrival cursor starts at B
-    (pending arrivals were consumed in earlier chunks), and ovf/ne reset
-    per chunk.
+    (pending arrivals were consumed in earlier chunks), and the ring peak
+    and ne reset per chunk.
     """
     R, Jc = chunk.arrival.shape
     C = int(slots.shape[0])
@@ -1899,7 +1919,7 @@ def _bs_inflate(canon: dict, chunk: BatchTrace, fed: int, slots,
                 heads[r, c] = loc[0]
     carry = (np.full(R, B, np.int32), st, canon["comp"], ring, heads,
              canon["W"], canon["t_prev"], canon["t_hol"],
-             np.zeros(R, bool), np.zeros(R, np.int32))
+             np.zeros(R, np.int32), np.zeros(R, np.int32))
     return carry, (arr, cl, nd, svc), idmap
 
 
@@ -1911,7 +1931,7 @@ def _bs_extract(carry, idmap, rec, B: int, C: int, q_cap: int) -> dict:
     any lane means the bounded local layout cannot represent the backlog
     — raised loudly rather than silently dropping jobs.
     """
-    ai, st, comp, ring, heads, W, t_prev, t_hol, ovf, ne = carry
+    ai, st, comp, ring, heads, W, t_prev, t_hol, rpk, ne = carry
     arr_l, cl_l, nd_l, svc_l = rec
     R = st.shape[0]
     canon = {"pend_gid": np.full((R, B), -1, np.int64),
@@ -2025,12 +2045,7 @@ def _bs_stream_drive(source, *, policy, chunk_jobs, total_jobs, part, slots,
         J_l = B + n
         length = 2 * n + B + C * s_max
         carry, tagged, rec_t = scan_fn(carry, rec, horizon, length)
-        ovf = carry[8]
-        if ovf.any():
-            raise RuntimeError(
-                f"helper-wait ring buffer overflow (queue_cap={q_cap}) in "
-                f"replication(s) {np.flatnonzero(ovf).tolist()} — workload "
-                f"unstable at this load, or raise queue_cap")
+        _bs_check_ring(carry[8], q_cap)
         if not np.all(carry[0] == J_l):
             raise RuntimeError("internal error: chunk scan left arrivals "
                                "unprocessed")
@@ -2127,11 +2142,11 @@ def _modbs_stream_jax(source, *, chunk_jobs, total_jobs, partition=None,
 
 
 #: dtypes of the BS stream carry (ai, st, comp, ring, heads, W, t_prev,
-#: t_hol, ovf, ne) — the host keeps the carry as numpy for extract /
+#: t_hol, ring peak, ne) — the host keeps the carry as numpy for extract /
 #: checkpoint; chunk calls re-device it with these.
 _BS_CARRY_DTYPES = (jnp.int32, jnp.int32, jnp.float64, jnp.int32,
                     jnp.int32, jnp.float64, jnp.float64, jnp.float64,
-                    jnp.bool_, jnp.int32)
+                    jnp.int32, jnp.int32)
 
 
 def _bs_chunk_scan_jax(C: int, s_max: int, h: int, q_cap: int):

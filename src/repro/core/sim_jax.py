@@ -427,7 +427,7 @@ def _bs_make_step(jobrec, C: int, s_max: int, h: int, q_cap: int):
         return jnp.take_along_axis(jobrec, idx[:, None, None], axis=1)[:, 0]
 
     def step(carry, _):
-        (ai, st, comp, ring, heads, W, t_prev, t_hol, ovf) = carry
+        (ai, st, comp, ring, heads, W, t_prev, t_hol, rpk) = carry
         # st packs the per-class int32 counters: [0:C] free A slots,
         # [C:2C] ring heads, [2C:3C] ring tails.
 
@@ -463,7 +463,9 @@ def _bs_make_step(jobrec, C: int, s_max: int, h: int, q_cap: int):
         ring = ring.at[lanes,
                        jnp.where(enq, c_arr * q_cap + tail_c % q_cap,
                                  C * q_cap)].set(j_arr, mode="drop")
-        ovf = ovf | (enq & (tail_c + 1 - head_c > q_cap))
+        # ring occupancy only grows at an enqueue: the running max over
+        # enqueues is the exact peak over every class
+        rpk = jnp.maximum(rpk, jnp.where(enq, tail_c + 1 - head_c, 0))
         ai = ai + jnp.where(is_arr, 1, 0)
 
         # --- A-completion: rule-3 pull the class head into the freed slot
@@ -538,13 +540,16 @@ def _bs_make_step(jobrec, C: int, s_max: int, h: int, q_cap: int):
         heads = heads.at[lanes1, hidx].set(hval, mode="drop")
 
         # one tagged int per event (fewer scan outputs = fewer per-step
-        # ops): j = A start, j + J = routed to H, j + 2J = helper commit
-        tagged = jnp.where(is_commit, jh + 2 * J,
+        # ops): j = A start, j + J = routed to H, j + 2J = helper commit,
+        # j + 3J = helper commit at the job's own arrival (a zero wait the
+        # host takes from its exact arrival; see _bs_scatter_events)
+        tagged = jnp.where(is_commit,
+                           jh + jnp.where(Th == rec_h[:, 0], 3 * J, 2 * J),
                            jnp.where(ins, j_ins,
                                      jnp.where(enq, j_arr + J, -1)))
         rec_t = jnp.where(is_commit, Th, t_ins)
         out = (tagged, rec_t)
-        return (ai, st, comp, ring, heads, W, t_prev, t_hol, ovf), out
+        return (ai, st, comp, ring, heads, W, t_prev, t_hol, rpk), out
 
     return step
 
@@ -563,7 +568,7 @@ def _bs_init(R: int, J: int, C: int, s_max: int, h: int, q_cap: int,
             jnp.zeros((R, h), dt),                      # W, sorted asc.
             jnp.zeros(R, dt),                           # t_prev
             jnp.zeros(R, dt),                           # t_hol
-            jnp.zeros(R, bool))                         # ring overflow
+            jnp.zeros(R, jnp.int32))                    # ring peak
 
 
 def _bs_core(arrival, cls, need, service, slots, s_max: int, h: int,
@@ -608,8 +613,11 @@ def _bs_core(arrival, cls, need, service, slots, s_max: int, h: int,
 
     Returns the raw per-event streams ``(tagged, rec_t)`` (each [R, 2J];
     tagged encodes j = A start, j + J = routed to H, j + 2J = helper
-    commit, -1 = no record) and a per-lane ring-overflow flag; the host
-    wrappers (`_bs_scatter_events`) scatter the events to per-job arrays.
+    commit, j + 3J = helper commit at the job's own arrival, -1 = no
+    record) and each lane's peak helper-wait ring occupancy (the most
+    jobs of one class queued at once; the ring overflowed where it
+    exceeds ``q_cap``); the host wrappers (`_bs_scatter_events`) scatter
+    the events to per-job arrays.
     """
     R, J = arrival.shape
     C = slots.shape[0]
@@ -620,14 +628,14 @@ def _bs_core(arrival, cls, need, service, slots, s_max: int, h: int,
                        axis=2)                            # [R, J, 4]
     step = _bs_make_step(jobrec, C, s_max, h, q_cap)
     carry0 = _bs_init(R, J, C, s_max, h, q_cap, slots, dt)
-    (_, _, _, _, _, _, _, _, ovf), (tagged, rec_t) \
+    (_, _, _, _, _, _, _, _, rpk), (tagged, rec_t) \
         = jax.lax.scan(step, carry0, None, length=2 * J)
 
     # ys are stacked [2J, R]; hand back [R, 2J] event streams.  The host
     # wrappers scatter them to per-job arrays with numpy — an in-graph
     # .at[job].set scatter looks natural here but XLA:CPU lowers the
     # unsorted scatter to a serial per-element loop that dwarfs the scan.
-    return tagged.T, rec_t.T, ovf
+    return tagged.T, rec_t.T, rpk
 
 
 
@@ -680,7 +688,7 @@ def _bs_stream_make_step(jobrec, horizon, C: int, s_max: int, h: int,
         return jnp.take_along_axis(jobrec, idx[:, None, None], axis=1)[:, 0]
 
     def step(carry, _):
-        (ai, st, comp, ring, heads, W, t_prev, t_hol, ovf, ne) = carry
+        (ai, st, comp, ring, heads, W, t_prev, t_hol, rpk, ne) = carry
 
         j_arr = jnp.minimum(ai, J - 1)
         rec_a = rec(j_arr)
@@ -714,7 +722,7 @@ def _bs_stream_make_step(jobrec, horizon, C: int, s_max: int, h: int,
         ring = ring.at[lanes,
                        jnp.where(enq, c_arr * q_cap + tail_c % q_cap,
                                  C * q_cap)].set(j_arr, mode="drop")
-        ovf = ovf | (enq & (tail_c + 1 - head_c > q_cap))
+        rpk = jnp.maximum(rpk, jnp.where(enq, tail_c + 1 - head_c, 0))
         ai = ai + jnp.where(is_arr, 1, 0)
 
         # --- A-completion: rule-3 pull
@@ -777,12 +785,13 @@ def _bs_stream_make_step(jobrec, horizon, C: int, s_max: int, h: int,
         hval = jnp.stack([j_arr, nxt], 1)
         heads = heads.at[lanes1, hidx].set(hval, mode="drop")
 
-        tagged = jnp.where(is_commit, jh + 2 * J,
+        tagged = jnp.where(is_commit,
+                           jh + jnp.where(Th == rec_h[:, 0], 3 * J, 2 * J),
                            jnp.where(ins, j_ins,
                                      jnp.where(enq, j_arr + J, -1)))
         rec_t = jnp.where(is_commit, Th, t_ins)
         out = (tagged, rec_t)
-        return (ai, st, comp, ring, heads, W, t_prev, t_hol, ovf, ne), out
+        return (ai, st, comp, ring, heads, W, t_prev, t_hol, rpk, ne), out
 
     return step
 
@@ -798,7 +807,7 @@ def _bs_stream_core(arrival, cls, need, service, horizon, carry,
     ``sim_batch._bs_rebase``) so every ring-buffer reference stays in
     bounds.  ``horizon`` [R] is the first arrival of the next chunk (inf
     when draining).  ``carry`` is the full event-scan state
-    ``(ai, st, comp, ring, heads, W, t_prev, t_hol, ovf, ne)``; the scan
+    ``(ai, st, comp, ring, heads, W, t_prev, t_hol, rpk, ne)``; the scan
     runs ``length`` steps (enough for every event dated before the
     horizon — trailing steps no-op) and returns the updated carry plus
     the tagged per-event record streams of ``_bs_core``.
@@ -861,7 +870,7 @@ def _bs_fail_make_step(jobrec, failrec, C: int, s_max: int, h: int,
         return jnp.take_along_axis(failrec, idx[:, None, None], axis=1)[:, 0]
 
     def step(carry, _):
-        (ai, fi, st, comp, ring, heads, W, t_prev, t_hol, ovf) = carry
+        (ai, fi, st, comp, ring, heads, W, t_prev, t_hol, rpk) = carry
 
         j_arr = jnp.minimum(ai, J - 1)
         rec_a = rec(j_arr)
@@ -899,7 +908,7 @@ def _bs_fail_make_step(jobrec, failrec, C: int, s_max: int, h: int,
         ring = ring.at[lanes,
                        jnp.where(enq, c_arr * q_cap + tail_c % q_cap,
                                  C * q_cap)].set(j_arr, mode="drop")
-        ovf = ovf | (enq & (tail_c + 1 - head_c > q_cap))
+        rpk = jnp.maximum(rpk, jnp.where(enq, tail_c + 1 - head_c, 0))
         ai = ai + jnp.where(is_arr, 1, 0)
 
         # --- A-completion: rule-3 pull
@@ -990,12 +999,13 @@ def _bs_fail_make_step(jobrec, failrec, C: int, s_max: int, h: int,
         hval = jnp.stack([j_arr, nxt], 1)
         heads = heads.at[lanes1, hidx].set(hval, mode="drop")
 
-        tagged = jnp.where(is_commit, jh + 2 * J,
+        tagged = jnp.where(is_commit,
+                           jh + jnp.where(Th == rec_h[:, 0], 3 * J, 2 * J),
                            jnp.where(ins, j_ins,
                                      jnp.where(enq, j_arr + J, -1)))
         rec_t = jnp.where(is_commit, Th, t_ins)
         out = (tagged, rec_t)
-        return (ai, fi, st, comp, ring, heads, W, t_prev, t_hol, ovf), out
+        return (ai, fi, st, comp, ring, heads, W, t_prev, t_hol, rpk), out
 
     return step
 
@@ -1041,32 +1051,42 @@ def _bs_fail_core(arrival, cls, need, service, ft, ftgt, fup, slots,
     return tagged, rec_t, carry[9]
 
 
-def _bs_scatter_events(J: int, tagged, rec_t):
+def _bs_scatter_events(J: int, tagged, rec_t, arrival):
     """Scatter [R, 2J] event records to per-job [R, J] arrays, all reps at
     once.
 
     ``tagged`` encodes the event: j = job j started in its A_i (the record
     time is its start), j + J = job j was routed to H on arrival, j + 2J =
-    job j started on a helper server.  Each job yields exactly one start
-    record and at most one routing record per replication, so every target
-    cell is written at most once and one flat advanced-indexing assignment
-    per record kind handles the whole batch — host post-processing stays
-    O(R·J) vectorized numpy instead of an R-iteration Python loop.
+    job j started on a helper server (the record time is its start), j +
+    3J = job j started on a helper server at its own arrival.  Each job
+    yields exactly one start record and at most one routing record per
+    replication, so every target cell is written at most once and one flat
+    advanced-indexing assignment per record kind handles the whole batch —
+    host post-processing stays O(R·J) vectorized numpy instead of an
+    R-iteration Python loop.
+
+    A job that was never routed took a free A_i slot on arrival (rule 1),
+    so it and a j + 3J job start at their own ``arrival`` ([R, J], the
+    host's float64), not at the record time: a chip that emulates float64
+    holds times to fewer bits, and a zero wait must stay exactly zero.
     """
     tagged = np.asarray(tagged)
     rec_t = np.asarray(rec_t)
     R = tagged.shape[0]
     rows = np.broadcast_to(np.arange(R)[:, None], tagged.shape)
-    start = np.zeros((R, J))
+    start = np.array(arrival, np.float64)
     served = np.zeros((R, J), bool)
     routed = np.zeros((R, J), bool)
-    m_a = (tagged >= 0) & (tagged < J)
     m_r = (tagged >= J) & (tagged < 2 * J)
-    m_h = tagged >= 2 * J
-    start[rows[m_a], tagged[m_a]] = rec_t[m_a]
     routed[rows[m_r], tagged[m_r] - J] = True
+    m_a = (tagged >= 0) & (tagged < J)
+    m_a[m_a] = routed[rows[m_a], tagged[m_a]]     # rule-3 pull-backs
+    start[rows[m_a], tagged[m_a]] = rec_t[m_a]
+    m_h = (tagged >= 2 * J) & (tagged < 3 * J)
     start[rows[m_h], tagged[m_h] - 2 * J] = rec_t[m_h]
     served[rows[m_h], tagged[m_h] - 2 * J] = True
+    m_h = tagged >= 3 * J
+    served[rows[m_h], tagged[m_h] - 3 * J] = True
     return start, served, routed
 
 

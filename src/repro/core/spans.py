@@ -24,7 +24,14 @@ checks, event-to-job scatters, the result), all with the call's
 ``srpt_pairwise_events`` (sum of the event steps, 2J per replication,
 that the ``jax`` and ``jax-shard`` SRPT scans ran with the slot table
 ordered by pairwise precedence counts, at Q up to the backend's
-``sim_jax._SRPT_PAIRWISE_MAX_Q``; absent when every scan sorted).
+``sim_jax._SRPT_PAIRWISE_MAX_Q``; absent when every scan sorted),
+``bs_ring_peak`` (the most jobs of one class that a BS-FCFS scan held
+in its helper-wait ring at once, over every replication: batch, grid,
+jax-shard, drain-mode and each chunk of a stream), ``bs_ring_cap`` (the
+largest ring size, ``queue_cap`` slots per class, such a scan ran with),
+``bs_helper_served`` (sum of the jobs that a BS-FCFS batch or grid
+result saw start on the helpers) and ``bs_jobs`` (sum of the jobs in
+those results).
 """
 
 from __future__ import annotations

@@ -225,7 +225,7 @@ def modbs_fail_scan_fwd(t, cls, need, svc, t_up, is_fail, slots, *,
 
 
 def _bs_kernel(a_ref, c_ref, n_ref, s_ref, sl_ref, tag_ref, rect_ref,
-               ovf_ref, *, s_max: int, h: int, q_cap: int):
+               rpk_ref, *, s_max: int, h: int, q_cap: int):
     # one replication per grid cell: run the batched step with R = 1
     arrival = a_ref[0, :][None]
     cls = c_ref[0, :][None]
@@ -249,18 +249,20 @@ def _bs_kernel(a_ref, c_ref, n_ref, s_ref, sl_ref, tag_ref, rect_ref,
         (carry0, jnp.zeros(2 * J, jnp.int32), jnp.zeros(2 * J, dt)))
     tag_ref[0, :] = tagged
     rect_ref[0, :] = rec_t
-    ovf_ref[0] = carry[-1][0]                             # ring overflow
+    rpk_ref[0] = carry[-1][0]                             # ring peak
 
 
 @functools.partial(
     jax.jit, static_argnames=("s_max", "h", "q_cap", "interpret"))
 def bs_scan_fwd(arrival, cls, need, service, slots, *, s_max: int,
                 h: int, q_cap: int, interpret: bool = False):
-    """[R, J] trace arrays -> (tagged [R, 2J] i32, rec_t [R, 2J], ovf [R]).
+    """[R, J] trace arrays -> (tagged [R, 2J] i32, rec_t [R, 2J], peak [R]).
 
     Same raw event-stream encoding as ``sim_jax._bs_core``: tagged j = job
     j started in its A_i, j + J = routed to H on arrival, j + 2J = helper
-    commit, -1 = non-recording event.
+    commit, j + 3J = helper commit at the job's own arrival, -1 =
+    non-recording event; peak = the lane's peak helper-wait ring
+    occupancy.
     """
     R, J = arrival.shape
     C = slots.shape[0]
@@ -274,13 +276,13 @@ def bs_scan_fwd(arrival, cls, need, service, slots, *, s_max: int,
                    pl.BlockSpec((1,), lambda r: (r,))),
         out_shape=(jax.ShapeDtypeStruct((R, 2 * J), jnp.int32),
                    jax.ShapeDtypeStruct((R, 2 * J), arrival.dtype),
-                   jax.ShapeDtypeStruct((R,), jnp.bool_)),
+                   jax.ShapeDtypeStruct((R,), jnp.int32)),
         interpret=interpret,
     )(arrival, cls, need, service, slots)
 
 
 def _bs_fail_kernel(a_ref, c_ref, n_ref, s_ref, ft_ref, ftgt_ref, fup_ref,
-                    sl_ref, tag_ref, rect_ref, ovf_ref, *, s_max: int,
+                    sl_ref, tag_ref, rect_ref, rpk_ref, *, s_max: int,
                     h: int, q_cap: int, length: int):
     arrival = a_ref[0, :][None]
     cls = c_ref[0, :][None]
@@ -308,7 +310,7 @@ def _bs_fail_kernel(a_ref, c_ref, n_ref, s_ref, ft_ref, ftgt_ref, fup_ref,
         (carry0, jnp.zeros(length, jnp.int32), jnp.zeros(length, dt)))
     tag_ref[0, :] = tagged
     rect_ref[0, :] = rec_t
-    ovf_ref[0] = carry[9][0]                              # ring overflow
+    rpk_ref[0] = carry[9][0]                              # ring peak
 
 
 @functools.partial(
@@ -317,7 +319,7 @@ def bs_fail_scan_fwd(arrival, cls, need, service, ft, ftgt, fup, slots, *,
                      s_max: int, h: int, q_cap: int, length: int,
                      interpret: bool = False):
     """Drain-mode BS-π scan: trace [R, J] + failures [R, F] -> event
-    streams (tagged [R, length] i32, rec_t [R, length], ovf [R]).
+    streams (tagged [R, length] i32, rec_t [R, length], peak [R]).
 
     ``length`` = 2J + F + F_A, per ``sim_batch._bs_fail_args``; failure
     events win ties and claim the earliest-free capacity unit of their
@@ -338,6 +340,6 @@ def bs_fail_scan_fwd(arrival, cls, need, service, ft, ftgt, fup, slots, *,
                    pl.BlockSpec((1,), lambda r: (r,))),
         out_shape=(jax.ShapeDtypeStruct((R, length), jnp.int32),
                    jax.ShapeDtypeStruct((R, length), arrival.dtype),
-                   jax.ShapeDtypeStruct((R,), jnp.bool_)),
+                   jax.ShapeDtypeStruct((R,), jnp.int32)),
         interpret=interpret,
     )(arrival, cls, need, service, ft, ftgt, fup, slots)

@@ -66,7 +66,7 @@ def modbs_scan(arrival, cls, need, service, *, slots, s_max: int, h: int):
 
 def bs_scan(arrival, cls, need, service, *, slots, s_max: int, h: int,
             q_cap: int):
-    """Fused BS-π (Def. 1) event scan -> (tagged, rec_t, ovf) streams."""
+    """Fused BS-π (Def. 1) event scan -> (tagged, rec_t, ring peak)."""
     return bs_scan_fwd(arrival, cls, need, service,
                        jnp.asarray(slots, jnp.int32),
                        s_max=s_max, h=h, q_cap=q_cap,
@@ -147,15 +147,15 @@ def _bs_pallas(batch, *, partition=None, wl=None, queue_cap=None,
     slots, s_max, h, q_cap = _bs_args(batch, partition, wl, queue_cap)
     if failures is None:
         with enable_x64():
-            tagged, rec_t, ovf = _fetch(_call(
+            tagged, rec_t, rpk = _fetch(_call(
                 lambda a, c, n, v: bs_scan(a, c, n, v, slots=slots,
                                            s_max=s_max, h=h, q_cap=q_cap),
                 *_class_inputs(batch)))
-        return _bs_result(batch, tagged, rec_t, ovf, q_cap)
+        return _bs_result(batch, tagged, rec_t, rpk, q_cap)
     flr.require_drain(failures, "pallas")
     ft, ftgt, fup, length = _bs_fail_args(batch, failures, partition, wl)
     with enable_x64():
-        tagged, rec_t, ovf = _fetch(_call(
+        tagged, rec_t, rpk = _fetch(_call(
             lambda a, c, n, v, t1, t2, t3: bs_fail_scan_fwd(
                 a, c, n, v, t1, t2, t3, jnp.asarray(slots, jnp.int32),
                 s_max=s_max, h=h, q_cap=q_cap, length=length,
@@ -163,7 +163,7 @@ def _bs_pallas(batch, *, partition=None, wl=None, queue_cap=None,
             *_class_inputs(batch),
             *_puts((ft, jnp.float64), (ftgt, jnp.int32),
                    (fup, jnp.float64))))
-    return _with_drain_obs(_bs_result(batch, tagged, rec_t, ovf, q_cap),
+    return _with_drain_obs(_bs_result(batch, tagged, rec_t, rpk, q_cap),
                            batch, failures)
 
 

@@ -40,6 +40,6 @@ def modbs_scan_ref(arrival, cls, need, service, *, slots, s_max: int,
 @partial(jax.jit, static_argnames=("s_max", "h", "q_cap"))
 def bs_scan_ref(arrival, cls, need, service, *, slots, s_max: int,
                 h: int, q_cap: int):
-    """Hand-vectorized BS-π event scan core -> (tagged, rec_t, ovf)."""
+    """Hand-vectorized BS-π event scan core -> (tagged, rec_t, ring peak)."""
     return _bs_core(arrival, cls, need, service,
                     jnp.asarray(slots, jnp.int32), s_max, h, q_cap)

@@ -222,6 +222,141 @@ def test_bs_queue_cap_overflow_raises():
         bs_sim(trace, wl=wl, queue_cap=4)
 
 
+def test_bs_ring_overflow_names_the_peak():
+    """The overflow error states the peak against ``queue_cap`` and
+    blames the ring's size, not the load."""
+    wl = figure1_workload(64, theta=0.7)
+    trace = wl.sample_trace(3000, seed=7)
+    with pytest.raises(RuntimeError) as err:
+        bs_sim(trace, wl=wl, queue_cap=4)
+    msg = str(err.value)
+    peak = int(msg.split("up to ")[1].split()[0])
+    assert peak > 4
+    assert "queue_cap=4" in msg and f"queue_cap >= {peak}" in msg
+    assert "unstable" not in msg
+
+
+def _oracle_ring_peaks(batch, wl) -> np.ndarray:
+    """Each replication's largest helper-wait backlog of one class, counted
+    from the python oracle's routing and start times: a routed job joins
+    its class's queue at its arrival and leaves it when it starts (helper
+    commit or rule-3 pull-back), so at job j's enqueue the queue holds j
+    and every earlier routed job of its class that starts after a_j."""
+    from repro.core.simulator import _make_python_policy
+    peaks = []
+    for r in range(batch.reps):
+        trace = batch.rep(r)
+        pol = _make_python_policy("bs-fcfs", None, wl)
+        sim = Simulation(trace, pol)
+        sim.run()
+        routed = np.zeros(trace.num_jobs, bool)
+        routed[sorted(pol.routed_jobs)] = True
+        peak = 0
+        for c in range(batch.C):
+            idx = np.flatnonzero(routed & (trace.cls == c))
+            a, s = trace.arrival[idx], sim.start_time[idx]
+            earlier = np.tri(idx.size, k=-1, dtype=bool)   # [j, i]: i < j
+            occ = 1 + (earlier & (s[None, :] > a[:, None])).sum(axis=1)
+            peak = max(peak, int(occ.max(initial=0)))
+        peaks.append(peak)
+    return np.array(peaks)
+
+
+def test_bs_sdsc_sp2_deployment_matches_oracle():
+    """The SDSC-SP2 deployment (Table 2, k=512, load 0.85) on its own
+    shape: block-bootstrapped replications of one Poisson base trace.
+    Here the helpers carry a quarter of the jobs and class n2 has no
+    block slot, so every n2 job waits in the helper ring.  ``jax`` and
+    ``jax-shard`` equal the oracle at rtol=0, and the ring peak the scan
+    reads back is the oracle's largest per-class backlog."""
+    from repro.core import engines, spans
+    from repro.core.sim_batch import (_bs_scan_batch, _bs_scatter_events,
+                                      _class_inputs)
+    from repro.core.sim_jax import _bs_args
+    from repro.core.workload import sdsc_sp2_workload
+
+    wl = sdsc_sp2_workload(k=512, load=0.85)
+    batch = BatchTrace.from_trace(wl.sample_trace(3000, seed=5), reps=4,
+                                  seed=9, method="block")
+    oracle = engines.simulate("bs-fcfs", batch, engine="python", wl=wl)
+    peaks = _oracle_ring_peaks(batch, wl)
+    for engine in ("jax", "jax-shard"):
+        spans.reset()
+        out = engines.simulate("bs-fcfs", batch, engine=engine, wl=wl)
+        for f in ("start", "wait", "p_helper", "p_routed"):
+            assert np.array_equal(getattr(out, f), getattr(oracle, f)), \
+                (engine, f)
+        c = spans.counters()
+        assert c["bs_ring_peak"] == peaks.max(), engine
+        assert c["bs_ring_cap"] == 3000
+        assert c["bs_jobs"] == 3000 * 4
+        assert c["bs_helper_served"] == round(oracle.p_helper.sum() * 3000)
+    assert (oracle.p_helper > 0.10).all()
+
+    # per job and per lane, from the raw event streams of the scan
+    slots, s_max, h, q_cap = _bs_args(batch, None, wl, None)
+    assert slots[1] == 0 and h == 69
+    with enable_x64():
+        tagged, rec_t, rpk = _bs_scan_batch(
+            *_class_inputs(batch), jnp.asarray(slots), s_max, h, q_cap)
+    _, served, _ = _bs_scatter_events(batch.num_jobs, tagged, rec_t,
+                                      batch.arrival)
+    n2 = batch.cls == 1
+    assert n2.sum() > 0 and served[n2].all()
+    assert np.array_equal(np.asarray(rpk), peaks)
+
+
+def _rounded_times(x, bits=44):
+    """``x`` held to ``bits`` bits of mantissa, as a chip that emulates
+    float64 with two float32 words holds it."""
+    m, e = np.frexp(np.asarray(x, np.float64))
+    return np.ldexp(np.round(m * 2.0**bits) / 2.0**bits, e)
+
+
+@pytest.mark.parametrize("path", ["batch", "stream"])
+def test_bs_zero_waits_stay_exact_under_rounded_times(path, monkeypatch):
+    """At SDSC-SP2 times (1e5-1e7 s) a record time rounded on the device
+    is off from the host's arrival by more than the 1e-9 s that counts as
+    a wait: a job that started at its own arrival takes that arrival, so
+    P(wait > 0) stays the oracle's exactly."""
+    from repro.core import engines, sim_batch
+    from repro.core.workload import sdsc_sp2_workload
+
+    wl = sdsc_sp2_workload(k=512, load=0.85)
+    batch = BatchTrace.from_trace(wl.sample_trace(3000, seed=5), reps=2,
+                                  seed=9, method="block")
+    oracle = engines.simulate("bs-fcfs", batch, engine="python", wl=wl)
+    moved = []
+
+    def rounded(rec_t):
+        out = _rounded_times(rec_t)
+        moved.append(int((out != rec_t).sum()))
+        return out
+
+    if path == "batch":
+        fetch = sim_batch._fetch
+        monkeypatch.setattr(sim_batch, "_fetch", lambda out, reps=None: (
+            lambda t, r, p: (t, rounded(r), p))(*fetch(out, reps)))
+        out = engines.simulate("bs-fcfs", batch, engine="jax", wl=wl)
+        p_wait = (out.wait > sim_batch.WAIT_EPS).mean(axis=1)
+    else:
+        chunk_scan = sim_batch._bs_chunk_scan_jax
+
+        def rounded_scan(*a):
+            scan = chunk_scan(*a)
+
+            def run(*b):
+                carry, tagged, rec_t = scan(*b)
+                return carry, tagged, rounded(rec_t)
+            return run
+        monkeypatch.setattr(sim_batch, "_bs_chunk_scan_jax", rounded_scan)
+        p_wait = engines.simulate_stream("bs-fcfs", batch, chunk_jobs=1000,
+                                         wl=wl).p_wait
+    assert sum(moved) > 1000
+    assert np.array_equal(p_wait,
+                          (oracle.wait > sim_batch.WAIT_EPS).mean(axis=1))
+
+
 # -- fused Pallas kernels (interpret mode on CPU) -----------------------------
 #
 # The rtol=0 contract of the msj_scan kernel family: grid cell r runs the

@@ -155,6 +155,39 @@ def test_srpt_counters_are_the_oracle_peak(policy, engine, side,
         assert "srpt_pairwise_events" not in c
 
 
+def _bs_run(path, batch, wl):
+    """One BS-FCFS call of ``batch`` on ``path``; the per-job result where
+    the path returns one."""
+    if path == "grid":
+        return engines.simulate_grid(
+            "bs-fcfs", [engines.GridCell(batch, wl=wl)], engine="jax")[0]
+    if path == "stream":
+        engines.simulate_stream("bs-fcfs", batch, chunk_jobs=100, wl=wl)
+        return None
+    return _simulate("bs-fcfs", path, batch, wl)
+
+
+@pytest.mark.parametrize("path", BATCH_ENGINES + ("grid", "stream"))
+def test_bs_ring_and_helper_counters(path):
+    """One BS-FCFS call raises the ring counters, the peak within the ring,
+    and a per-job result adds its helper-served and total jobs."""
+    wl = _workload(load=0.95)
+    batch = wl.sample_traces(300, 4, seed=3)
+    spans.reset()
+    out = _bs_run(path, batch, wl)
+    c = spans.counters()
+    assert 0 < c["bs_ring_peak"] <= c["bs_ring_cap"]
+    if out is None:
+        # a stream's ring holds the backlog cap and one chunk
+        assert c["bs_ring_cap"] == 1024 + 100
+        assert "bs_jobs" not in c
+        return
+    assert c["bs_ring_cap"] == 300
+    assert c["bs_jobs"] == 300 * 4
+    assert c["bs_helper_served"] == round(out.p_helper.sum() * 300)
+    assert c["bs_helper_served"] > 0
+
+
 def test_counters_sum_high_copy_reset():
     spans.reset()
     spans.add("n", 3)
