@@ -655,8 +655,8 @@ def bs_sim_batch(batch: BatchTrace,
 # * k-padding (dead capacity): heterogeneous k / C / s_max / h share one
 #   static shape by moving every per-cell size into the *initial carry* —
 #   dead servers are ``_BIG`` entries at the tail of the sorted free-time
-#   vectors (no finite completion ever undercuts them, so searchsorted
-#   positions and n-th-smallest reads see exactly the live prefix), and
+#   vectors (no finite completion ever undercuts them, so insertion
+#   counts and n-th-smallest reads see exactly the live prefix), and
 #   dead A-slots are permanently-busy ``_BIG`` completion entries (the
 #   same masking ``_modbs_init`` uses for ragged slot counts, and the
 #   drain-mode failure machinery uses for outages).

@@ -44,8 +44,9 @@ O(k) sorted-invariant step.  W is kept sorted ascending as a scan invariant
 instead of re-sorted every arrival (O(k log k) per job).  Each of the n
 retired entries satisfies W[i] <= W[n-1] <= T_j <= T_j + S_j, so removing
 the n smallest and inserting n copies of comp = T_j + S_j is a roll-and-
-insert:  with p = searchsorted(W, comp, 'right') - n, the new sorted vector
-is  [W[n:n+p], comp * n, W[n+p:]] — a single O(k) gather.  The pre-fix
+insert:  with p = #{i : W[i] <= comp} - n, the new sorted vector is
+[W[n:n+p], comp * n, W[n+p:]] — dense compares and selects over W, with
+W[n:] built from static rolls by the bits of n (no gather).  The pre-fix
 full-sort step is retained as ``_fcfs_scan_reference`` and the two paths
 are cross-validated bit-for-bit in ``tests/test_sim_cross.py``.
 
@@ -134,21 +135,39 @@ def loss_queue_sim(arrival: np.ndarray, service: np.ndarray, s: int) -> JaxSimRe
 # --------------------------------------------------------------------------
 
 
+def _shift_left(W, n):
+    """``W[i + n]`` at every i < k - n, without a gather (``n`` traced).
+
+    ``W`` is rolled left by the bits of ``n``: one static roll and a
+    select per bit, ``(k - 1).bit_length()`` stages, so the step holds no
+    data-dependent index (under vmap a dynamic slice or index lowers to a
+    per-lane gather).  Entries at i >= k - n wrap around; callers never
+    read them.
+    """
+    k = W.shape[0]
+    for b in range((k - 1).bit_length()):
+        W = jnp.where(((n >> b) & 1) == 1, jnp.roll(W, -(1 << b)), W)
+    return W
+
+
 def _fcfs_sorted_step(W, t_prev, t, n, svc):
     """One Kiefer–Wolfowitz arrival on a sorted free-time vector, O(k).
 
     Requires W sorted ascending; returns (W', start) with W' sorted.
+    Dense compares, selects, reductions and static rolls only: no gather
+    and no search loop.
     """
-    k = W.shape[0]
-    nth = W[jnp.maximum(n - 1, 0)]
+    i = jnp.arange(W.shape[0], dtype=jnp.int32)
+    # W is sorted, so its n-th smallest entry is the largest of the first n.
+    nth = jnp.max(jnp.where(i < jnp.maximum(n, 1), W, -jnp.inf))
     start = jnp.maximum(jnp.maximum(t, t_prev), nth)
     comp = start + svc
     # All n retired entries are <= comp, so the remainder W[n:] shifted left
-    # with n copies of comp inserted at offset p stays sorted.
-    p = jnp.searchsorted(W, comp, side="right") - n
-    i = jnp.arange(k)
-    W_new = jnp.where((i >= p) & (i < p + n), comp,
-                      W[jnp.where(i < p, i + n, i)])
+    # with n copies of comp inserted at offset p stays sorted; p <= k - n,
+    # so the shifted part never reads past the end of W.
+    p = jnp.sum(W <= comp, dtype=jnp.int32) - n
+    W_new = jnp.where(i < p, _shift_left(W, n),
+                      jnp.where(i < p + n, comp, W))
     return W_new, start
 
 
@@ -215,11 +234,10 @@ def _kw_drain(W, t_up):
     ``t_up = 0`` is the identity — the no-op padding row of the merged
     failure stream.
     """
-    k = W.shape[0]
     comp_f = jnp.maximum(W[0], t_up)
-    p = jnp.searchsorted(W, comp_f, side="right") - 1
-    i = jnp.arange(k)
-    return jnp.where(i == p, comp_f, W[jnp.where(i < p, i + 1, i)])
+    p = jnp.sum(W <= comp_f, dtype=jnp.int32) - 1
+    i = jnp.arange(W.shape[0], dtype=jnp.int32)
+    return jnp.where(i < p, jnp.roll(W, -1), jnp.where(i == p, comp_f, W))
 
 
 def _fcfs_fail_step(carry, inp):

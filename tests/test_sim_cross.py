@@ -14,6 +14,7 @@ rank/permute network is additionally property-tested against
 """
 
 import heapq
+import re
 
 import numpy as np
 import pytest
@@ -23,7 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax import enable_x64
 
-from repro.core import sim_jax
+from repro.core import sim_batch, sim_jax
 from repro.core.policies import make_policy
 from repro.core.sim_batch import (bs_sim_batch, fcfs_sim_batch,
                                   loss_queue_sim_batch, modified_bs_sim_batch)
@@ -104,24 +105,69 @@ def test_fcfs_batched_matches_single():
         assert np.array_equal(b.response[r], single.response)
 
 
-def test_fcfs_sorted_step_bitexact_vs_sort_reference():
+@pytest.mark.parametrize("k,n_jobs",
+                         [(8, 500), (64, 2000), (94, 2000), (256, 2000),
+                          (1024, 2000)])
+def test_fcfs_sorted_step_bitexact_vs_sort_reference(k, n_jobs):
     """The O(k) roll-and-insert must equal the O(k log k) sort step
-    bit-for-bit, including tied arrivals and zero service times."""
-    rng = np.random.default_rng(12)
-    for k, n_jobs in ((8, 500), (64, 2000), (256, 2000)):
-        arrival = np.cumsum(rng.exponential(0.05, n_jobs))
-        arrival[1::7] = arrival[0::7][: len(arrival[1::7])]  # inject ties
-        arrival = np.sort(arrival)
+    bit-for-bit, including tied arrivals, zero service times and jobs of
+    need k and 1; at k = 1024 the needs are fig1-critical's."""
+    rng = np.random.default_rng(12 + k)
+    arrival = np.cumsum(rng.exponential(0.05, n_jobs))
+    arrival[1::7] = arrival[0::7][: len(arrival[1::7])]  # inject ties
+    arrival = np.sort(arrival)
+    if k == 1024:
+        need = figure1_workload(k).sample_trace(n_jobs, seed=k).need.copy()
+    else:
         need = rng.integers(1, max(2, k // 4), size=n_jobs)
-        service = np.where(rng.random(n_jobs) < 0.2, 0.0,
-                           rng.exponential(1.0, n_jobs))
-        with enable_x64():
-            args = (jnp.asarray(arrival, jnp.float64),
-                    jnp.asarray(need, jnp.int32),
-                    jnp.asarray(service, jnp.float64), k)
-            fast = np.asarray(sim_jax._fcfs_scan(*args))
-            ref = np.asarray(sim_jax._fcfs_scan_reference(*args))
-        assert np.array_equal(fast, ref), f"k={k}"
+    need[5::97] = k
+    need[7::13] = 1
+    service = np.where(rng.random(n_jobs) < 0.2, 0.0,
+                       rng.exponential(1.0, n_jobs))
+    with enable_x64():
+        args = (jnp.asarray(arrival, jnp.float64),
+                jnp.asarray(need, jnp.int32),
+                jnp.asarray(service, jnp.float64), k)
+        fast = np.asarray(sim_jax._fcfs_scan(*args))
+        ref = np.asarray(sim_jax._fcfs_scan_reference(*args))
+    assert np.array_equal(fast, ref), f"k={k}"
+
+
+@pytest.mark.parametrize("k", [8, 94, 1024])
+def test_kw_drain_bitexact_vs_sort(k):
+    """A drain on the sorted vector equals the sort-based update: raise the
+    earliest free time to max(W[0], t_up), then sort.  t_up falls below W,
+    on tied entries, between entries, above all of W, and at 0."""
+    rng = np.random.default_rng(k)
+    W = np.sort(np.round(rng.exponential(2.0, k), 1))   # rounding ties
+    drain = jax.jit(sim_jax._kw_drain)
+    with enable_x64():
+        for step in range(60):
+            t_up = (0.0, W[0] / 2, W[rng.integers(k)],
+                    W[rng.integers(k)] + 0.05, W[-1] + 1.0)[step % 5]
+            ref = W.copy()
+            ref[0] = max(ref[0], t_up)
+            ref = np.sort(ref)
+            W = np.asarray(drain(jnp.asarray(W, jnp.float64),
+                                 jnp.float64(t_up)))
+            assert np.array_equal(W, ref), f"k={k} step={step}"
+
+
+@pytest.mark.filterwarnings("ignore:Some donated buffers")
+def test_fcfs_scans_hold_no_gather_or_search_loop():
+    """The compiled FCFS batch and drain scans at fig1's k = 1024, R = 16:
+    no gather and one while (the scan's own), so no search loop or
+    per-lane indexing comes back into the step."""
+    R, J, k = 16, 64, 1024
+    f = jax.ShapeDtypeStruct((R, J), jnp.float64)
+    i = jax.ShapeDtypeStruct((R, J), jnp.int32)
+    b = jax.ShapeDtypeStruct((R, J), jnp.bool_)
+    with enable_x64():
+        for fn, args in ((sim_batch._fcfs_scan_batch, (f, i, f)),
+                         (sim_batch._fcfs_fail_scan_batch, (f, i, f, f, b))):
+            txt = fn.lower(*args, k=k).compile().as_text()
+            assert not re.search(r"\bgather\(", txt), fn.__name__
+            assert len(re.findall(r"\bwhile\(", txt)) == 1, fn.__name__
 
 
 def test_fcfs_full_need_jobs():
@@ -570,7 +616,7 @@ def test_unknown_engine_raises():
 
 # -- O(k) roll-and-insert under ties (property test) --------------------------
 #
-# Duplicated arrival/service values drive searchsorted(W, comp, "right")
+# Duplicated arrival/service values drive the insertion count #{W <= comp}
 # into tied boundaries (comp equal to one or more entries of W, tied
 # arrivals, zero services).  The O(k) sorted-invariant step, the retained
 # full-sort reference, and the fused Pallas kernel must agree bit-for-bit
